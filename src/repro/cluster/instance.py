@@ -35,6 +35,7 @@ from __future__ import annotations
 import queue
 import threading
 import time
+import traceback
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional
 
@@ -62,6 +63,8 @@ class InstanceStats:
     solo_fallbacks: int = 0  # batches degraded to per-message execution
     handoffs: int = 0        # queued messages forwarded to peers on reassignment
     reassignments: int = 0   # drain-and-handoff cycles completed
+    errors: int = 0          # stage-fn / tick() exceptions (each also a drop)
+    first_error: str = ""    # traceback of the first of them
     busy_s: float = 0.0
     window_start: float = field(default_factory=time.monotonic)
 
@@ -388,6 +391,14 @@ class WorkflowInstance:
         for m in msgs:
             self.rd.mark_dropped(m.uid_hex)
 
+    def _record_error(self) -> None:
+        """Count a stage-fn or ``tick()`` exception and keep the first
+        traceback: the drop it causes stays §9-accounted, but a kernel
+        that fails every request must not look like a quiet queue."""
+        self.stats.errors += 1
+        if not self.stats.first_error:
+            self.stats.first_error = f"{self.name}: {traceback.format_exc()}"
+
     def drain_terminal(self) -> None:
         """Terminal accounting: whatever is still sitting in the worker queue
         or the inbox after the threads exit was admitted but will never be
@@ -584,6 +595,7 @@ class WorkflowInstance:
             except Exception:
                 # a dying decode batch: abandon every resident request of
                 # this fn with §9 accounting rather than kill the scheduler
+                self._record_error()
                 try:
                     fn.abandon()
                 except Exception:
@@ -733,6 +745,7 @@ class WorkflowInstance:
                 try:
                     results.append(fn(m.payload, uid=m.uid_hex))
                 except Exception:
+                    self._record_error()
                     results.append(_DROP)
             return results
         sizes = None
@@ -743,6 +756,7 @@ class WorkflowInstance:
             return unstack_payload(fn(payload), sizes)
         except Exception:
             if sizes is None and len(msgs) == 1:
+                self._record_error()
                 return [_DROP]  # the raw call itself failed; a retry is identical
         self.stats.solo_fallbacks += 1
         results = []
@@ -750,6 +764,7 @@ class WorkflowInstance:
             try:
                 results.append(fn(m.payload))
             except Exception:
+                self._record_error()
                 results.append(_DROP)
         return results
 
@@ -852,6 +867,7 @@ class WorkflowInstance:
                 partials[i] = fn(payload, worker_idx=i, n_workers=self.n_workers)
             except Exception:
                 errors[i] = True
+                self._record_error()
 
         threads = [threading.Thread(target=run, args=(i,)) for i in range(self.n_workers)]
         for t in threads:

@@ -85,12 +85,15 @@ class WorkflowSet:
         is lock-instrumented (pytest, REPRO_LOCK_CHECK=1), ``lock_stats``
         carries per-lock-name contention counters — acquisitions,
         contended count, total/max wait and hold (docs/static_analysis.md);
-        {} in production."""
+        {} in production.  ``stage_errors``/``first_error`` count the
+        instances' stage-fn and ``tick()`` exceptions."""
         total = ChannelStats()
         for p in self.proxies:
             total = total.merge(p.transport_stats())
         for inst in self.instances.values():
             total = total.merge(inst.rd.transport_stats())
+            total.stage_errors += inst.stats.errors
+            total.first_error = total.first_error or inst.stats.first_error
         total.lock_stats = lock_stats_snapshot()
         prof = profiler()
         if prof.enabled:

@@ -1,4 +1,4 @@
-"""qwen3-1.7b [dense] — qk_norm, GQA [hf:Qwen/Qwen3-8B]."""
+"""qwen3-1.7b [dense] — qk_norm, GQA [hf:Qwen/Qwen3-1.7B]."""
 from repro.configs.base import ModelConfig
 
 CONFIG = ModelConfig(
@@ -14,5 +14,5 @@ CONFIG = ModelConfig(
     qk_norm=True,
     rope_theta=1_000_000.0,
     tie_embeddings=True,
-    source="hf:Qwen/Qwen3-8B",
+    source="hf:Qwen/Qwen3-1.7B",
 )

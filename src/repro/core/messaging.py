@@ -15,6 +15,7 @@ import uuid as uuidlib
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Tuple, Union
 
+import ml_dtypes  # noqa: F401 -- registers bfloat16/float8/int4 with numpy
 import numpy as np
 
 _HDR = struct.Struct("<16sdIIQ")  # uuid, timestamp, app_id, stage, payload_len
@@ -58,10 +59,19 @@ class KVPages:
 
 def _tensor_view(x: np.ndarray) -> Buf:
     """Zero-copy byte view of a contiguous array (copies only if the input
-    was non-contiguous and ascontiguousarray had to materialize it)."""
+    was non-contiguous and ascontiguousarray had to materialize it).  The
+    ``uint8`` view works for every dtype, the ml_dtypes extensions
+    (bfloat16, float8, int4) included, which the buffer protocol refuses."""
     if x.size == 0:
-        return b""  # memoryview cannot cast a view with zeros in its shape
-    return memoryview(np.ascontiguousarray(x)).cast("B")
+        return b""
+    return memoryview(np.ascontiguousarray(x).reshape(-1).view(np.uint8))
+
+
+def _dtype_tag(dt: np.dtype) -> str:
+    """A dtype name ``np.dtype`` maps back: the type string for numpy's own
+    types, the registered name for the ml_dtypes extensions, whose type
+    string is an opaque void (``'<V2'`` for bfloat16)."""
+    return dt.name if dt.kind == "V" else dt.str
 
 
 def _encode_payload_parts(payload: Payload) -> List[Buf]:
@@ -74,14 +84,15 @@ def _encode_payload_parts(payload: Payload) -> List[Buf]:
     if isinstance(payload, (bytes, bytearray, memoryview)):
         return [struct.pack("<B", _KIND_BYTES), payload]
     if isinstance(payload, np.ndarray):
-        meta = json.dumps({"dtype": payload.dtype.str, "shape": payload.shape}).encode()
+        meta = json.dumps({"dtype": _dtype_tag(payload.dtype),
+                           "shape": payload.shape}).encode()
         return [struct.pack("<BI", _KIND_TENSOR, len(meta)), meta,
                 _tensor_view(payload)]
     if isinstance(payload, KVPages):
         pages = [np.asarray(p) for p in payload.pages]
         meta = json.dumps({
             "meta": payload.meta,
-            "pages": [{"dtype": p.dtype.str, "shape": list(p.shape)}
+            "pages": [{"dtype": _dtype_tag(p.dtype), "shape": list(p.shape)}
                       for p in pages]}).encode()
         parts: List[Buf] = [
             struct.pack("<BII", _KIND_KVPAGES, len(meta), len(pages)), meta]
@@ -91,7 +102,7 @@ def _encode_payload_parts(payload: Payload) -> List[Buf]:
             parts.append(view)
         return parts
     # generic pytree: JSON skeleton with tensor leaves hoisted to a blob list
-    blobs: List[memoryview] = []
+    blobs: List[Buf] = []
 
     def hoist(x):
         if isinstance(x, np.generic):
@@ -99,7 +110,10 @@ def _encode_payload_parts(payload: Payload) -> List[Buf]:
         if isinstance(x, np.ndarray):
             blobs.append(_tensor_view(x))
             return {"__tensor__": len(blobs) - 1,
-                    "dtype": x.dtype.str, "shape": list(x.shape)}
+                    "dtype": _dtype_tag(x.dtype), "shape": list(x.shape)}
+        if isinstance(x, (bytes, bytearray, memoryview)):
+            blobs.append(memoryview(x).cast("B"))
+            return {"__bytes__": len(blobs) - 1}
         if isinstance(x, dict):
             return {k: hoist(v) for k, v in x.items()}
         if isinstance(x, (list, tuple)):
@@ -148,6 +162,8 @@ def _decode_payload(raw: Buf) -> Payload:
 
         def lower(x):
             if isinstance(x, dict):
+                if "__bytes__" in x:
+                    return bytes(blobs[x["__bytes__"]])
                 if "__tensor__" in x:
                     return np.frombuffer(
                         blobs[x["__tensor__"]], dtype=np.dtype(x["dtype"])

@@ -49,6 +49,10 @@ class ChannelStats:
     # shipment, and the raw cache bytes inside them
     kv_pages: int = 0
     kv_bytes: int = 0
+    # stage-fn / tick() exceptions across the set's instances, and the
+    # first one's traceback; filled by WorkflowSet.transport_stats()
+    stage_errors: int = 0
+    first_error: str = ""
     # per-lock-name contention stats (repro.analysis.runtime.LockStats
     # dicts); populated by WorkflowSet.transport_stats() when the suite
     # runs with lock instrumentation, {} otherwise
@@ -67,6 +71,8 @@ class ChannelStats:
             batches=self.batches + other.batches,
             kv_pages=self.kv_pages + other.kv_pages,
             kv_bytes=self.kv_bytes + other.kv_bytes,
+            stage_errors=self.stage_errors + other.stage_errors,
+            first_error=self.first_error or other.first_error,
             lock_stats={**self.lock_stats, **other.lock_stats},
             latency={**self.latency, **other.latency},
         )

@@ -40,10 +40,8 @@ def kernel_mode(interpret=None) -> str:
 
 from repro.kernels.flash_attention.ops import flash_attention  # noqa: E402
 from repro.kernels.decode_attention.ops import (  # noqa: E402
-    decode_attention,
     decode_attention_cache,
     decode_attention_int8_cache,
-    decode_attention_quantized,
     quantize_kv,
 )
 from repro.kernels.rwkv6_wkv.ops import wkv6  # noqa: E402
@@ -54,10 +52,8 @@ __all__ = [
     "auto_interpret",
     "kernel_mode",
     "flash_attention",
-    "decode_attention",
     "decode_attention_cache",
     "decode_attention_int8_cache",
-    "decode_attention_quantized",
     "quantize_kv",
     "wkv6",
     "ddim_step",

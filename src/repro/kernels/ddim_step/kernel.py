@@ -16,8 +16,10 @@ seed's DAG tests); the kernel does the combine + update in one pass over
 the latent so each sampling step reads x/eps once and writes once instead
 of materializing x0 and two broadcast intermediates.
 
-The latent is flattened and tiled [num_blocks, block]; coefficients ride
-in SMEM so traced alphas (indexed out of the schedule inside the jitted
+The latent is flattened to lane-dense rows of 128 and tiled
+[block_rows, 128] (block_rows a multiple of 8, the f32 sublane tile), so
+every block satisfies the TPU (8, 128) tiling rule; coefficients ride in
+SMEM so traced alphas (indexed out of the schedule inside the jitted
 sampling loop) stay on-device.
 """
 from __future__ import annotations
@@ -33,25 +35,26 @@ from jax.experimental.pallas import tpu as pltpu
 def _ddim_kernel(coef_ref, x_ref, eps_ref, o_ref):
     c1 = coef_ref[0]
     c2 = coef_ref[1]
-    x = x_ref[0].astype(jnp.float32)
-    eps = eps_ref[0].astype(jnp.float32)
-    o_ref[0] = (c1 * x + c2 * eps).astype(o_ref.dtype)
+    x = x_ref[...].astype(jnp.float32)
+    eps = eps_ref[...].astype(jnp.float32)
+    o_ref[...] = (c1 * x + c2 * eps).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("block", "interpret"))
-def ddim_step_blocked(x2d, eps2d, coefs, *, block: int, interpret=False):
-    """x2d/eps2d: [num_blocks, block]; coefs: f32 [2] = (c1, c2)."""
-    nb, bl = x2d.shape
-    assert bl == block
+@functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
+def ddim_step_blocked(x2d, eps2d, coefs, *, block_rows: int, interpret=False):
+    """x2d/eps2d: [rows, 128] with rows % block_rows == 0; coefs: f32 [2]
+    = (c1, c2)."""
+    rows, lanes = x2d.shape
+    assert rows % block_rows == 0, (rows, block_rows)
     return pl.pallas_call(
         _ddim_kernel,
-        grid=(nb,),
+        grid=(rows // block_rows,),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, block), lambda i: (i, 0)),
-            pl.BlockSpec((1, block), lambda i: (i, 0)),
+            pl.BlockSpec((block_rows, lanes), lambda i: (i, 0)),
+            pl.BlockSpec((block_rows, lanes), lambda i: (i, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((nb, block), x2d.dtype),
+        out_specs=pl.BlockSpec((block_rows, lanes), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((rows, lanes), x2d.dtype),
         interpret=interpret,
     )(coefs, x2d, eps2d)

@@ -1,15 +1,11 @@
 from repro.kernels.decode_attention.ops import (
-    decode_attention,
     decode_attention_cache,
     decode_attention_int8_cache,
-    decode_attention_quantized,
     quantize_kv,
 )
 
 __all__ = [
-    "decode_attention",
     "decode_attention_cache",
     "decode_attention_int8_cache",
-    "decode_attention_quantized",
     "quantize_kv",
 ]

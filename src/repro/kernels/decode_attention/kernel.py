@@ -5,11 +5,11 @@ goal is streaming the cache through VMEM exactly once).
 Grid: (batch, kv_heads, num_kv_blocks); trailing dim sequential with the
 online-softmax state (m, l, acc over the q-group rows) in VMEM scratch.
 
-Two cache layouts are supported:
-  [B, S, KV, D]  — the kernel-native layout the original wrappers exposed
-  [B, KV, S, D]  — the model's serving layout (GEMM-ready per head); the
-                   ``*_cache`` variants index it directly so the dispatch
-                   layer never relayouts the cache on the decode hot path.
+The cache is the model's serving layout [B, KV, S, D] (GEMM-ready per
+head): the kernels index it directly, so the dispatch layer never
+relayouts the cache on the decode hot path.  Per-position int8 scales
+arrive as [B, KV, 1, S], so their blocks end in (1, block_k) — the full
+array dim and a lane multiple, as the TPU's (8, 128) tiling rule asks.
 
 The int8 variants consume the quantized cache from ``quantize_kv`` without
 materializing a dequantized block: k scales fold into the score matrix
@@ -31,12 +31,12 @@ NEG_INF = -1e30
 def _online_softmax_block(q, k, v, kj, block_k, cur, m_ref, l_ref, acc_ref,
                           k_scale=None, v_scale=None):
     """One kv block of the decode online softmax.  q: [G,D] (pre-scaled);
-    k/v: [bk,D] f32; optional per-position scales [bk] fold into the score
-    columns (k) and probabilities (v)."""
+    k/v: [bk,D] f32; optional per-position scales [1, bk] fold into the
+    score columns (k) and probabilities (v)."""
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)  # [G, bk]
     if k_scale is not None:
-        s = s * k_scale[None, :]
+        s = s * k_scale
     pos = kj * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     s = jnp.where(pos <= cur, s, NEG_INF)
     m_prev = m_ref[...]
@@ -45,7 +45,7 @@ def _online_softmax_block(q, k, v, kj, block_k, cur, m_ref, l_ref, acc_ref,
     corr = jnp.exp(m_prev - m_new)
     l_ref[...] = l_ref[...] * corr + p.sum(axis=-1)
     if v_scale is not None:
-        p = p * v_scale[None, :]
+        p = p * v_scale
     acc_ref[...] = acc_ref[...] * corr[:, None] + p @ v
     m_ref[...] = m_new
 
@@ -62,8 +62,7 @@ def _init_state(m_ref, l_ref, acc_ref):
 
 
 def _decode_kernel(idx_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
-                   *, block_k: int, num_kv_blocks: int, sm_scale: float,
-                   cache_layout: bool):
+                   *, block_k: int, num_kv_blocks: int, sm_scale: float):
     kj = pl.program_id(2)
 
     @pl.when(kj == 0)
@@ -75,12 +74,8 @@ def _decode_kernel(idx_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
     @pl.when(kj * block_k <= cur)
     def _body():
         q = q_ref[0, 0].astype(jnp.float32) * sm_scale        # [G, D]
-        if cache_layout:  # [1, 1, bk, D] block of a [B,KV,S,D] cache
-            k = k_ref[0, 0].astype(jnp.float32)
-            v = v_ref[0, 0].astype(jnp.float32)
-        else:             # [1, bk, 1, D] block of a [B,S,KV,D] cache
-            k = k_ref[:, :, 0].reshape(block_k, -1).astype(jnp.float32)
-            v = v_ref[:, :, 0].reshape(block_k, -1).astype(jnp.float32)
+        k = k_ref[0, 0].astype(jnp.float32)                   # [bk, D]
+        v = v_ref[0, 0].astype(jnp.float32)
         _online_softmax_block(q, k, v, kj, block_k, cur, m_ref, l_ref, acc_ref)
 
     @pl.when(kj == num_kv_blocks - 1)
@@ -90,8 +85,7 @@ def _decode_kernel(idx_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
 
 def _decode_kernel_int8(idx_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref,
                         m_ref, l_ref, acc_ref, *, block_k: int,
-                        num_kv_blocks: int, sm_scale: float,
-                        cache_layout: bool):
+                        num_kv_blocks: int, sm_scale: float):
     """int8-quantized cache variant: the cache feeds the dots directly and
     the per-(head, position) scales fold into scores / probabilities —
     HBM traffic is 1/2 of bf16, 1/4 of f32, with no dequantized block ever
@@ -107,13 +101,9 @@ def _decode_kernel_int8(idx_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref,
     @pl.when(kj * block_k <= cur)
     def _body():
         q = q_ref[0, 0].astype(jnp.float32) * sm_scale                 # [G, D]
-        if cache_layout:
-            kq = k_ref[0, 0].astype(jnp.float32)                       # [bk, D]
-            vq = v_ref[0, 0].astype(jnp.float32)
-        else:
-            kq = k_ref[:, :, 0].reshape(block_k, -1).astype(jnp.float32)
-            vq = v_ref[:, :, 0].reshape(block_k, -1).astype(jnp.float32)
-        ks = ks_ref[0, 0]                                              # [bk]
+        kq = k_ref[0, 0].astype(jnp.float32)                           # [bk, D]
+        vq = v_ref[0, 0].astype(jnp.float32)
+        ks = ks_ref[0, 0]                                              # [1, bk]
         vs = vs_ref[0, 0]
         _online_softmax_block(q, kq, vq, kj, block_k, cur, m_ref, l_ref,
                               acc_ref, k_scale=ks, v_scale=vs)
@@ -132,39 +122,9 @@ def _state_scratch(g, d):
 
 
 @functools.partial(jax.jit, static_argnames=("block_k", "interpret"))
-def decode_attention_grouped(q, k_cache, v_cache, cur_index, *,
-                             block_k=512, interpret=False):
-    """q: [B,KV,G,D]; k/v_cache: [B,S,KV,D]; cur_index: int32 scalar."""
-    b, kv, g, d = q.shape
-    s = k_cache.shape[1]
-    block_k = min(block_k, s)
-    assert s % block_k == 0
-    nk = s // block_k
-    idx = jnp.asarray(cur_index, jnp.int32).reshape(1)
-
-    kernel = functools.partial(_decode_kernel, block_k=block_k,
-                               num_kv_blocks=nk, sm_scale=d ** -0.5,
-                               cache_layout=False)
-    return pl.pallas_call(
-        kernel,
-        grid=(b, kv, nk),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),  # cur index (scalar)
-            pl.BlockSpec((1, 1, g, d), lambda b_, n, j: (b_, n, 0, 0)),
-            pl.BlockSpec((1, block_k, 1, d), lambda b_, n, j: (b_, j, n, 0)),
-            pl.BlockSpec((1, block_k, 1, d), lambda b_, n, j: (b_, j, n, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, g, d), lambda b_, n, j: (b_, n, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, kv, g, d), q.dtype),
-        scratch_shapes=_state_scratch(g, d),
-        interpret=interpret,
-    )(idx, q, k_cache, v_cache)
-
-
-@functools.partial(jax.jit, static_argnames=("block_k", "interpret"))
 def decode_attention_grouped_cache(q, k_cache, v_cache, cur_index, *,
                                    block_k=512, interpret=False):
-    """Serving-layout variant: q [B,KV,G,D]; k/v_cache [B,KV,S,D]."""
+    """q [B,KV,G,D]; k/v_cache [B,KV,S,D]; cur_index: int32 scalar."""
     b, kv, g, d = q.shape
     s = k_cache.shape[2]
     block_k = min(block_k, s)
@@ -173,8 +133,7 @@ def decode_attention_grouped_cache(q, k_cache, v_cache, cur_index, *,
     idx = jnp.asarray(cur_index, jnp.int32).reshape(1)
 
     kernel = functools.partial(_decode_kernel, block_k=block_k,
-                               num_kv_blocks=nk, sm_scale=d ** -0.5,
-                               cache_layout=True)
+                               num_kv_blocks=nk, sm_scale=d ** -0.5)
     return pl.pallas_call(
         kernel,
         grid=(b, kv, nk),
@@ -192,44 +151,12 @@ def decode_attention_grouped_cache(q, k_cache, v_cache, cur_index, *,
 
 
 @functools.partial(jax.jit, static_argnames=("block_k", "interpret"))
-def decode_attention_int8_grouped(q, k_q, v_q, k_scale, v_scale, cur_index, *,
-                                  block_k=512, interpret=False):
-    """q: [B,KV,G,D]; k_q/v_q: int8 [B,S,KV,D]; scales: f32 [B,KV,S]."""
-    b, kv, g, d = q.shape
-    s = k_q.shape[1]
-    block_k = min(block_k, s)
-    assert s % block_k == 0
-    nk = s // block_k
-    idx = jnp.asarray(cur_index, jnp.int32).reshape(1)
-
-    kernel = functools.partial(_decode_kernel_int8, block_k=block_k,
-                               num_kv_blocks=nk, sm_scale=d ** -0.5,
-                               cache_layout=False)
-    return pl.pallas_call(
-        kernel,
-        grid=(b, kv, nk),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1, g, d), lambda b_, n, j: (b_, n, 0, 0)),
-            pl.BlockSpec((1, block_k, 1, d), lambda b_, n, j: (b_, j, n, 0)),
-            pl.BlockSpec((1, block_k, 1, d), lambda b_, n, j: (b_, j, n, 0)),
-            pl.BlockSpec((1, 1, block_k), lambda b_, n, j: (b_, n, j)),
-            pl.BlockSpec((1, 1, block_k), lambda b_, n, j: (b_, n, j)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, g, d), lambda b_, n, j: (b_, n, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, kv, g, d), q.dtype),
-        scratch_shapes=_state_scratch(g, d),
-        interpret=interpret,
-    )(idx, q, k_q, v_q, k_scale, v_scale)
-
-
-@functools.partial(jax.jit, static_argnames=("block_k", "interpret"))
 def decode_attention_int8_grouped_cache(q, k_q, v_q, k_scale, v_scale,
                                         cur_index, *, block_k=512,
                                         interpret=False):
-    """Serving-layout int8 variant: q [B,KV,G,D]; k_q/v_q int8 [B,KV,S,D];
-    scales f32 [B,KV,S] — exactly what the model's int8 decode cache holds,
-    so the dispatch layer hands the cache over with zero relayout."""
+    """int8 variant: q [B,KV,G,D]; k_q/v_q int8 [B,KV,S,D]; scales f32
+    [B,KV,1,S] — the model's int8 decode cache with a unit axis inserted
+    (a free reshape), so the cache is handed over with zero relayout."""
     b, kv, g, d = q.shape
     s = k_q.shape[2]
     block_k = min(block_k, s)
@@ -238,8 +165,7 @@ def decode_attention_int8_grouped_cache(q, k_q, v_q, k_scale, v_scale,
     idx = jnp.asarray(cur_index, jnp.int32).reshape(1)
 
     kernel = functools.partial(_decode_kernel_int8, block_k=block_k,
-                               num_kv_blocks=nk, sm_scale=d ** -0.5,
-                               cache_layout=True)
+                               num_kv_blocks=nk, sm_scale=d ** -0.5)
     return pl.pallas_call(
         kernel,
         grid=(b, kv, nk),
@@ -248,8 +174,8 @@ def decode_attention_int8_grouped_cache(q, k_q, v_q, k_scale, v_scale,
             pl.BlockSpec((1, 1, g, d), lambda b_, n, j: (b_, n, 0, 0)),
             pl.BlockSpec((1, 1, block_k, d), lambda b_, n, j: (b_, n, j, 0)),
             pl.BlockSpec((1, 1, block_k, d), lambda b_, n, j: (b_, n, j, 0)),
-            pl.BlockSpec((1, 1, block_k), lambda b_, n, j: (b_, n, j)),
-            pl.BlockSpec((1, 1, block_k), lambda b_, n, j: (b_, n, j)),
+            pl.BlockSpec((1, 1, 1, block_k), lambda b_, n, j: (b_, n, 0, j)),
+            pl.BlockSpec((1, 1, 1, block_k), lambda b_, n, j: (b_, n, 0, j)),
         ],
         out_specs=pl.BlockSpec((1, 1, g, d), lambda b_, n, j: (b_, n, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, kv, g, d), q.dtype),

@@ -1,8 +1,12 @@
+"""Multi-pod dry-run: lower and compile every (arch x shape) case on 512
+virtual CPU devices.  A CPU-only tool: it pins ``JAX_PLATFORMS=cpu`` for
+itself and the children it starts, so it never takes a chip."""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
-# The two lines above MUST run before any jax import (jax locks the device
-# count on first init); everything else follows.
+# The lines above MUST run before any jax import (jax locks the platform
+# and device count on first init); everything else follows.
 import argparse
 import json
 import pathlib

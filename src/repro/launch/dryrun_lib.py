@@ -203,15 +203,6 @@ def analytic_min_bytes(cfg: ModelConfig, shape: ShapeConfig, n_chips: int) -> fl
     return float(total) / n_chips
 
 
-def xla_cost_analysis(compiled) -> Dict[str, Any]:
-    """Normalised ``compiled.cost_analysis()``: jax < 0.6 returns a
-    one-element list of dicts, newer versions return the dict itself."""
-    ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca)
-
-
 def roofline_terms(stats: Dict[str, Any], hw=V5E) -> Dict[str, float]:
     """cost_analysis numbers are per-device; terms are per-chip seconds."""
     compute_s = stats["flops_per_chip"] / hw.peak_flops_bf16
@@ -250,7 +241,7 @@ def run_case(arch: str, shape_id: str, *, multi_pod: bool = False,
     t_compile = time.time() - t0
 
     mem = compiled.memory_analysis()
-    ca = xla_cost_analysis(compiled)
+    ca = compiled.cost_analysis()
     hlo_text = compiled.as_text()
     # trip-count-aware totals (XLA cost_analysis counts while bodies once —
     # useless for scan-over-layers models; see launch/hlo_analysis.py)

@@ -19,8 +19,6 @@ same d_ff sharding and the same single psum.
 """
 from __future__ import annotations
 
-import functools
-import inspect
 from typing import Any, Dict
 
 import jax
@@ -28,15 +26,6 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.models.param import ParamSpec, current_partitioner
-
-try:  # jax >= 0.6 exposes shard_map at top level
-    shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
-
-# the replication-check kwarg was renamed check_rep -> check_vma in jax 0.6
-_SHMAP_CHECK_KW = ("check_vma" if "check_vma" in
-                   inspect.signature(shard_map).parameters else "check_rep")
 
 Tree = Dict[str, Any]
 
@@ -171,8 +160,8 @@ def moe_ffn(x: jax.Array, lp, cfg: ModelConfig):
     if shared is not None:
         args += list(shared)
         in_specs += list(sh_spec)
-    out = shard_map(local, mesh=mesh, in_specs=tuple(in_specs),
-                    out_specs=tok, **{_SHMAP_CHECK_KW: False})(*args)
+    out = jax.shard_map(local, mesh=mesh, in_specs=tuple(in_specs),
+                        out_specs=tok, check_vma=False)(*args)
     return out, aux
 
 

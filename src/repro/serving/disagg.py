@@ -42,6 +42,13 @@ from repro.serving.engine import ServingEngine
 APP_LLM_DISAGG = 7
 
 
+def shipment_wire_bytes(engine: ServingEngine) -> int:
+    """Ring bytes one KVPages shipment can take: its pages plus headers,
+    per-page lengths and the JSON meta, whose prompt ids (at most
+    ``max_len`` of them, <= 12 characters each) dominate."""
+    return engine.shipment_bytes + 4096 + 12 * engine.max_len
+
+
 def make_prefill_fn(engine: ServingEngine) -> Callable[[Any], Any]:
     """Stage fn for the prefill half.
 
@@ -212,17 +219,19 @@ def build_llm_disagg_set(
     n_decode: int = 1,
     inline: bool = True,
     control_loop: bool = False,
-    ring_bytes: int = 1 << 24,
     prefill_time_s: float = 0.01,
     decode_time_s: float = 0.05,
 ) -> Tuple[WorkflowSet, "ContinuousDecoder"]:
     """Wire a two-stage llm_disagg Workflow Set around one engine.
 
-    The decode ring is sized up (``ring_bytes``) because each inbound
-    message is a whole KV cache; the decoder publishes per-segment
-    partials to the set's replicated database and purges them on
-    completion.  Returns ``(set, decoder)`` — the decoder is shared by
-    every decode instance, so all of them feed one slot batch.
+    Each message into a decode inbox is a whole KV cache, so that ring is
+    sized from the engine's per-request shipment: it holds a full slot
+    batch plus one prefill batch of shipments in flight (the prefill
+    inbox carries prompts only and keeps the instance default).  The
+    decoder publishes per-segment partials to the set's replicated
+    database and purges them on completion.  Returns ``(set, decoder)`` —
+    the decoder is shared by every decode instance, so all of them feed
+    one slot batch.
     """
     ws = WorkflowSet(name, control_loop=control_loop)
     db = ws.database
@@ -245,8 +254,8 @@ def build_llm_disagg_set(
     for i in range(n_prefill):
         ws.add_instance(f"prefill{i}", stage="prefill",
                         max_batch=prefill_batch, max_wait_s=max_wait_s,
-                        pad_to_full=prefill_batch > 1, inline=inline,
-                        ring_bytes=ring_bytes)
+                        pad_to_full=prefill_batch > 1, inline=inline)
+    ring_bytes = (max_slots + prefill_batch) * shipment_wire_bytes(engine)
     for i in range(n_decode):
         ws.add_instance(f"decode{i}", stage="decode", max_batch=1,
                         inline=inline, ring_bytes=ring_bytes)

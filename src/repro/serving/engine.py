@@ -256,6 +256,15 @@ class ServingEngine:
         """Tree (matching the cache tree) of each leaf's batch-axis index."""
         return self._batch_axes
 
+    @property
+    def shipment_bytes(self) -> int:
+        """Page bytes of one prefilled request on the wire: its B=1 cache
+        leaves at ``max_len`` plus the f32 last-token logits row."""
+        spec1 = registry.abstract_cache(self.cfg, 1, self.max_len)
+        cache = sum(int(np.prod(s.shape)) * jnp.dtype(s.dtype).itemsize
+                    for s in jax.tree.leaves(spec1, is_leaf=is_spec))
+        return cache + 4 * self.cfg.vocab_padded
+
     def init_slots(self, max_slots: int) -> Dict[str, Any]:
         """Fresh continuous-batching decode state: a ``max_slots``-wide slot
         cache plus per-slot sampling/progress vectors, all inactive."""
@@ -342,3 +351,18 @@ class ServingEngine:
                                          jnp.int32(p + i))
         return GenerationResult(tokens=np.concatenate(out, axis=1),
                                 prompt_len=p, steps=steps)
+
+    def teacher_forced_logits(self, prompts: np.ndarray,
+                              tokens: np.ndarray) -> np.ndarray:
+        """[B, V] f32 logits scoring the token after ``prompts`` [B, P]
+        followed by ``tokens`` [B, T] — the same prefill and scalar-index
+        decode programs as ``generate``, one step at a time.  The reference
+        a served stream is held to where it departs from a solo run."""
+        p = prompts.shape[1]
+        assert p + tokens.shape[1] <= self.max_len
+        logits, cache = self._prefill(self.params, self._prefill_batch(prompts))
+        for t in range(tokens.shape[1]):
+            logits, cache = self._decode(self.params, cache,
+                                         jnp.asarray(tokens[:, t], jnp.int32),
+                                         jnp.int32(p + t))
+        return np.asarray(logits)

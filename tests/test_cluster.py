@@ -165,6 +165,29 @@ def test_uid_tracks_request_through_lifecycle():
         assert results[u] == np.float32(i * 2 + 1)
 
 
+def test_stage_error_counted_with_traceback():
+    """A stage fn that raises still drops its request under §9, but the
+    set counts the exception and keeps its traceback."""
+    def refused(payload):
+        raise RuntimeError("kernel refused by the compiler")
+
+    ws = WorkflowSet("err", control_loop=False)
+    ws.register_workflow(WorkflowSpec(1, "wf", [StageSpec("s", fn=refused)]))
+    ws.add_instance("s0", stage="s")
+    p = ws.add_proxy("p0")
+    with ws:
+        uid = p.submit(1, {"x": np.zeros(2, np.float32)})
+        deadline = time.monotonic() + 10
+        while uid not in ws.joins.dropped_snapshot() and \
+                time.monotonic() < deadline:
+            time.sleep(0.005)
+    stats = ws.transport_stats()
+    assert stats.stage_errors == 1
+    assert "Traceback" in stats.first_error
+    assert "kernel refused by the compiler" in stats.first_error
+    assert uid in ws.dead_uids()
+
+
 def test_instance_sharing_across_workflows():
     """§8.3: two apps share the 'mul' stage instances, diverge afterwards."""
     ws = WorkflowSet("share")

@@ -130,6 +130,52 @@ def test_disagg_partial_streaming(engine):
     np.testing.assert_array_equal(final, _solo(engine, pl))
 
 
+def test_decode_ring_holds_the_shipments_in_flight(engine):
+    """The decode inbox is sized from the engine's per-request shipment,
+    so no configuration drops every KV ship on ring capacity."""
+    import jax
+
+    from repro.serving.disagg import shipment_wire_bytes
+
+    logits, cache = engine.prefill(np.zeros((1, 4), np.int32))
+    pages = [np.asarray(logits)[0]] + [np.asarray(x)
+                                       for x in jax.tree.leaves(cache)]
+    assert engine.shipment_bytes == sum(p.nbytes for p in pages)
+    ws, _ = build_llm_disagg_set(engine, name="ring", max_slots=3,
+                                 prefill_batch=2)
+    ring = ws.instances["ring.decode0"].inbox.buf_size
+    assert ring >= (3 + 2) * engine.shipment_bytes
+    msg = WorkflowMessage.new(APP_LLM_DISAGG, payload=KVPages(
+        meta={"prompt": list(range(engine.max_len)), "start": 4,
+              "steps": 8, "temperature": 0.0, "seed": 0}, pages=pages))
+    assert sum(len(p) for p in msg.pack_parts()) <= shipment_wire_bytes(engine)
+
+
+def test_decode_tick_error_fails_the_run(engine, monkeypatch):
+    """A decode segment that raises abandons its requests under §9 and
+    surfaces as a stage error with its traceback in the run's failures."""
+    from repro.launch.serve import run_failures, wait_results
+
+    ws, dec = build_llm_disagg_set(engine, name="tickerr", max_slots=2,
+                                   segment_len=2)
+
+    def broken(state, k):
+        raise RuntimeError("decode segment refused")
+
+    monkeypatch.setattr(dec.engine, "decode_segment", broken)
+    with ws:
+        p = ws.proxies[0]
+        uid = p.submit(APP_LLM_DISAGG, _payload(engine, 0))
+        results, timed_out = wait_results(ws, p, [uid], 30.0)
+    assert results == {} and timed_out == 0
+    stats = ws.transport_stats()
+    assert stats.stage_errors >= 1
+    assert "decode segment refused" in stats.first_error
+    fails = run_failures(ws)
+    assert any("decode segment refused" in f for f in fails)
+    assert any("dead" in f for f in fails)
+
+
 # ==================================================== fault injection
 def test_kv_ship_dropped_mid_writev_is_accounted(engine):
     """The decode-bound KV-page writev is lost on the wire: the consumer

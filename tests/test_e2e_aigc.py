@@ -165,6 +165,30 @@ def test_a2v_nested_dag_serves_end_to_end(pipe):
     assert ws.instances["a2v.image_encode_0"].stats.processed == 2
 
 
+def test_serve_exits_nonzero_with_traceback_on_stage_error(monkeypatch,
+                                                          capsys):
+    """A failing stage anywhere on the served path fails the launcher
+    run, printing the traceback — never a quiet timeout and exit 0."""
+    from repro.launch import serve
+
+    real = serve.build_dag_stage_fns
+
+    def with_refused_diffusion(pipe):
+        fns = real(pipe)
+
+        def refused(payload):
+            raise RuntimeError("diffusion kernel refused")
+
+        return dict(fns, diffusion=refused)
+
+    monkeypatch.setattr(serve, "build_dag_stage_fns", with_refused_diffusion)
+    rc = serve.main(["--workflow", "dag", "--requests", "2", "--no-elastic"])
+    out = capsys.readouterr().out
+    assert rc != 0
+    assert "stage errors" in out and "diffusion kernel refused" in out
+    assert "Traceback" in out
+
+
 def test_theorem1_plan_for_measured_stage_times(pipe):
     times = measure_stage_times(pipe)
     chain = [times[s] for s in STAGES]

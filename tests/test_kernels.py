@@ -13,7 +13,7 @@ pytestmark = pytest.mark.slow  # JAX-heavy: excluded from the fast tier via -m "
 from repro.kernels.ddim_step import ddim_step
 from repro.kernels.ddim_step.ref import ddim_step_ref
 from repro.kernels.decode_attention import (
-    decode_attention, decode_attention_cache, decode_attention_int8_cache)
+    decode_attention_cache, decode_attention_int8_cache)
 from repro.kernels.decode_attention.ref import decode_attention_ref
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.flash_attention.ref import attention_ref
@@ -136,6 +136,11 @@ def test_wkv6_matches_model_scan():
 
 
 # ------------------------------------------------------------ decode attention
+def _serving(cache):
+    """[B,S,KV,D] oracle layout -> the model's [B,KV,S,D] serving layout."""
+    return cache.transpose(0, 2, 1, 3)
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("s,h,kv,d,bk,cur", [
     (512, 4, 2, 64, 128, 511),
@@ -148,7 +153,8 @@ def test_decode_attention_sweep(dtype, s, h, kv, d, bk, cur):
     q = rand(0, (b, h, d), dtype)
     kc = rand(1, (b, s, kv, d), dtype)
     vc = rand(2, (b, s, kv, d), dtype)
-    out = decode_attention(q, kc, vc, jnp.int32(cur), block_k=bk)
+    out = decode_attention_cache(q, _serving(kc), _serving(vc), jnp.int32(cur),
+                                 block_k=bk)
     ref = decode_attention_ref(q, kc, vc, jnp.int32(cur))
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32), **TOLS[dtype])
@@ -161,10 +167,9 @@ def test_decode_attention_matches_model_layer():
     q = rand(0, (b, h, d), jnp.float32)
     kc = rand(1, (b, s, kv, d), jnp.float32)
     vc = rand(2, (b, s, kv, d), jnp.float32)
-    out = decode_attention(q, kc, vc, jnp.int32(77))
-    # the model stores the cache in the [B,KV,S,hd] serving layout
-    mod = attention_decode(q, kc.transpose(0, 2, 1, 3), vc.transpose(0, 2, 1, 3),
-                           jnp.int32(77))
+    out = decode_attention_cache(q, _serving(kc), _serving(vc), jnp.int32(77))
+    mod = attention_decode(q, _serving(kc), _serving(vc), jnp.int32(77),
+                           use_pallas=False)
     np.testing.assert_allclose(np.asarray(out), np.asarray(mod), atol=3e-5, rtol=3e-5)
 
 
@@ -174,8 +179,7 @@ def test_decode_attention_matches_model_layer():
     (256, 8, 8, 128, 100),
 ])
 def test_decode_attention_int8_matches_dequantized_oracle(s, h, kv, d, cur):
-    from repro.kernels.decode_attention.ops import (
-        decode_attention_quantized, quantize_kv)
+    from repro.kernels.decode_attention.ops import quantize_kv
 
     b = 2
     q = rand(0, (b, h, d), jnp.float32)
@@ -183,7 +187,8 @@ def test_decode_attention_int8_matches_dequantized_oracle(s, h, kv, d, cur):
     vc = rand(2, (b, s, kv, d), jnp.float32)
     k_q, k_s = quantize_kv(kc)
     v_q, v_s = quantize_kv(vc)
-    out = decode_attention_quantized(q, k_q, v_q, k_s, v_s, jnp.int32(cur))
+    out = decode_attention_int8_cache(q, _serving(k_q), _serving(v_q), k_s, v_s,
+                                      jnp.int32(cur))
     # oracle on the dequantized cache: must match tightly
     deq_k = k_q.astype(jnp.float32) * k_s.transpose(0, 2, 1)[..., None]
     deq_v = v_q.astype(jnp.float32) * v_s.transpose(0, 2, 1)[..., None]
@@ -316,7 +321,8 @@ if HAVE_HYPOTHESIS:
         q = rand(0, (b, h, d), jnp.float32)
         kc = rand(1, (b, s, h, d), jnp.float32)
         vc = rand(2, (b, s, h, d), jnp.float32)
-        out = decode_attention(q, kc, vc, jnp.int32(cur), block_k=64)
+        out = decode_attention_cache(q, _serving(kc), _serving(vc),
+                                     jnp.int32(cur), block_k=64)
         ref = decode_attention_ref(q, kc, vc, jnp.int32(cur))
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=3e-5, rtol=3e-5)

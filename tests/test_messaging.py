@@ -35,6 +35,47 @@ def test_roundtrip_tensor_dtypes(dtype):
     np.testing.assert_array_equal(m2.payload, x)
 
 
+_WIRE_DTYPES = ["bfloat16", "int8", "float8_e4m3fn"]
+
+
+def _wire_array(dtype, shape=(1, 8, 5, 16)):
+    import ml_dtypes  # noqa: F401 -- registers the extension dtype names
+
+    return (np.arange(np.prod(shape)) % 251 - 125).reshape(shape).astype(dtype)
+
+
+@pytest.mark.parametrize("dtype", _WIRE_DTYPES)
+def test_roundtrip_tensor_dtype_bytes_intact(dtype):
+    """Model dtypes cross the fabric with dtype, shape and bytes intact —
+    bfloat16 has no buffer-protocol format and a void type string."""
+    x = _wire_array(dtype)
+    out = WorkflowMessage.unpack(
+        b"".join(bytes(p) for p in WorkflowMessage.new(1, payload=x).pack_parts())
+    ).payload
+    assert out.dtype == x.dtype and out.shape == x.shape
+    assert out.tobytes() == x.tobytes()
+    tree = WorkflowMessage.unpack(
+        WorkflowMessage.new(1, payload={"x": [x]}).pack()).payload
+    assert tree["x"][0].dtype == x.dtype
+    assert tree["x"][0].tobytes() == x.tobytes()
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "int8"])
+def test_roundtrip_kvpages_cache_dtypes(dtype):
+    from repro.core.messaging import KVPages
+
+    logits = np.linspace(-3, 3, 64, dtype=np.float32)
+    pages = [logits, _wire_array(dtype), _wire_array(dtype, (1, 8, 5))]
+    kv = KVPages(meta={"start": 5, "seed": 1}, pages=pages)
+    out = WorkflowMessage.unpack(
+        b"".join(bytes(p) for p in WorkflowMessage.new(7, payload=kv).pack_parts())
+    ).payload
+    assert out.meta == kv.meta and out.nbytes == kv.nbytes
+    for got, want in zip(out.pages, pages):
+        assert got.dtype == want.dtype and got.shape == want.shape
+        assert got.tobytes() == want.tobytes()
+
+
 def test_roundtrip_pytree():
     payload = {
         "latents": np.random.randn(2, 4, 8).astype(np.float32),
