@@ -43,6 +43,7 @@ from repro.analysis.runtime import make_lock
 from repro.cluster.database import ReplicatedDatabase
 from repro.cluster.join import JOIN_DEAD, JOIN_PENDING, JoinTable
 from repro.cluster.node_manager import NodeManager
+from repro.core import profiling
 from repro.core.batching import Coalescer, bucket_key, stack_payloads, unstack_payload
 from repro.core.messaging import KVPages, WorkflowMessage
 from repro.core.profiling import profiler
@@ -555,14 +556,15 @@ class WorkflowInstance:
         caller's empty poll and this wait is observed here (fast return);
         one set *during* the wait wakes it; a stale doorbell just costs
         one extra poll.  No interleaving loses a wakeup."""
-        if not self.event_driven:
-            self._stop.wait(timeout)
-            return
-        if self._doorbell.is_set():
+        with profiling.span("onepiece.sched.wait", instance=self.name):
+            if not self.event_driven:
+                self._stop.wait(timeout)
+                return
+            if self._doorbell.is_set():
+                self._doorbell.clear()
+                return  # traffic landed since the last poll: repoll now
+            self._doorbell.wait(timeout)
             self._doorbell.clear()
-            return  # traffic landed since the last poll: repoll now
-        self._doorbell.wait(timeout)
-        self._doorbell.clear()
 
     def _pump_continuous(self) -> bool:
         """Tick every continuous stage fn holding parked messages: one tick
@@ -588,6 +590,7 @@ class WorkflowInstance:
                 continue
             by_fn.setdefault(id(fn), (fn, []))[1].append(uid)
         pending = False
+        prof = profiler()
         for fn, uids in by_fn.values():
             t0 = time.monotonic()
             try:
@@ -601,12 +604,19 @@ class WorkflowInstance:
                 except Exception:
                     pass
                 done = [(u, _DROP) for u in uids]
-            self.stats.busy_s += time.monotonic() - t0
+            t1 = time.monotonic()
+            self.stats.busy_s += t1 - t0
             for uid, result in done:
                 with self._cont_lock:
                     m = self._deferred.pop(uid, None)
                 if m is None:
                     continue  # already accounted (drain/reassign race)
+                if prof.enabled:
+                    # the hop's stage_fn is its residence: from the
+                    # admission the fn reported to the tick that ended it
+                    prof.stamp(uid, m.stage, "fn_start",
+                               t=prof.admitted(uid) or t0)
+                    prof.stamp(uid, m.stage, "fn_end", t=t1)
                 self._deliver_results([m], [result])
             try:
                 if fn.pending() > 0:
@@ -620,12 +630,11 @@ class WorkflowInstance:
         # max_batch=1 instances bypass the coalescer entirely: no bucket
         # bookkeeping, no deadline arithmetic — poll, unpack, dispatch.
         bypass = self.max_batch <= 1
-        prof = profiler()
         while not self._stop.is_set():
             self._apply_reassignment(coalescer)
             cont_busy = self._pump_continuous()
-            item = self.inbox.poll()
-            if item is None:
+            msg = self._receive()
+            if msg is None:
                 if cont_busy:
                     continue  # slots still decoding: tick again, don't park
                 if bypass:
@@ -647,18 +656,8 @@ class WorkflowInstance:
                                       max(dl - time.monotonic(), 0.0))
                 self._wait_for_traffic(timeout)
                 continue
-            if isinstance(item, type(CORRUPT)):
-                self.stats.dropped += 1  # checksum-failed entry, no retry (§9)
+            if msg is _DROP:
                 continue
-            try:
-                msg = WorkflowMessage.unpack(item)
-            except Exception:
-                self.stats.dropped += 1
-                continue
-            if isinstance(msg.payload, KVPages) and self.rd.joins is not None:
-                self.rd.joins.settle_wire(msg.uid_hex)  # KV ship arrived
-            if prof.enabled:
-                prof.stamp(msg.uid_hex, msg.stage, "dequeue")
             if bypass:
                 self._dispatch([msg])
                 continue
@@ -678,6 +677,40 @@ class WorkflowInstance:
         for _, batch in coalescer.flush_all():
             self.stats.dropped += len(batch)
             self._mark_dropped_msgs(batch)
+
+    def _receive(self):
+        """One inbox poll: the decoded message, None when the inbox is
+        empty, or ``_DROP`` for an entry that was counted dropped.  While
+        a trace is recorded, only a poll that will find an entry is made,
+        inside an ``onepiece.recv`` span with its decode."""
+        if not profiling.tracing():
+            return self._decode(self.inbox.poll())
+        if not self.inbox.ready():
+            return None
+        with profiling.span("onepiece.recv", instance=self.name) as sp:
+            item = self.inbox.poll()
+            msg = self._decode(item)
+            if sp and isinstance(msg, WorkflowMessage):
+                sp.set_metadata(uid=msg.uid_hex, bytes=len(item))
+        return msg
+
+    def _decode(self, item):
+        if item is None:
+            return None
+        if isinstance(item, type(CORRUPT)):
+            self.stats.dropped += 1  # checksum-failed entry, no retry (§9)
+            return _DROP
+        try:
+            msg = WorkflowMessage.unpack(item)
+        except Exception:
+            self.stats.dropped += 1
+            return _DROP
+        if isinstance(msg.payload, KVPages) and self.rd.joins is not None:
+            self.rd.joins.settle_wire(msg.uid_hex)  # KV ship arrived
+        prof = profiler()
+        if prof.enabled:
+            prof.stamp(msg.uid_hex, msg.stage, "dequeue")
+        return msg
 
     # ------------------------------------------------------------- workers
     def _stage_entry(self, msg: WorkflowMessage) -> tuple:
@@ -777,13 +810,22 @@ class WorkflowInstance:
             self._mark_dropped_msgs(msgs)
             return
         prof = profiler()
+        # a continuous stage only parks the messages here: its fn_start
+        # and fn_end come from the pump (``_pump_continuous``)
+        stamp = prof.enabled and not is_continuous(fn)
         t0 = time.monotonic()
-        if prof.enabled:
+        if stamp:
             for m in msgs:
                 prof.stamp(m.uid_hex, m.stage, "fn_start", t=t0)
-        results = self._run_batch(fn, msgs)
+        with profiling.span("onepiece.stage", instance=self.name) as sp:
+            if sp:
+                sp.set_metadata(stage=self._stage_name_of(msgs[0]),
+                                uids=profiling.enter_batch(msgs))
+            results = self._run_batch(fn, msgs)
+            if sp:
+                profiling.leave_batch()
         t1 = time.monotonic()
-        if prof.enabled:
+        if stamp:
             for m in msgs:
                 prof.stamp(m.uid_hex, m.stage, "fn_end", t=t1)
         self.stats.busy_s += t1 - t0
@@ -831,10 +873,16 @@ class WorkflowInstance:
         # new payloads; `pairs` keeps the originals (source stage intact)
         # for the profiler's `delivered` stamp below.
         out = [m.for_stage(m.stage, r) for m, r in pairs]
-        if len(out) == 1:
-            ok = 1 if self.rd.deliver(out[0], stage, self.buffers) else 0
-        else:
-            ok = self.rd.deliver_many(out, stage, self.buffers)
+        with profiling.span("onepiece.deliver", instance=self.name,
+                            stage=stage) as sp:
+            if sp:
+                sp.set_metadata(uids=profiling.uids_arg(out),
+                                bytes=profiling.nbytes_arg(
+                                    m.payload for m in out))
+            if len(out) == 1:
+                ok = 1 if self.rd.deliver(out[0], stage, self.buffers) else 0
+            else:
+                ok = self.rd.deliver_many(out, stage, self.buffers)
         self.stats.delivered += ok
         self.stats.dropped += len(out) - ok
         prof = profiler()

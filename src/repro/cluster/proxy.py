@@ -20,6 +20,7 @@ from repro.cluster.database import ReplicatedDatabase
 from repro.cluster.join import JoinTable
 from repro.cluster.node_manager import NodeManager
 from repro.core.messaging import WorkflowMessage
+from repro.core.profiling import span
 from repro.core.rdma import RdmaFabric
 from repro.core.request_monitor import RequestMonitor
 from repro.core.ring_buffer import DoubleRingBuffer
@@ -93,18 +94,21 @@ class Proxy:
         immediately and the UID tombstoned, so branch copies that landed
         before the failure die at their next join (downstream drops are
         invisible to the proxy and only expire via the monitor's TTL)."""
-        entrances = self._entrances(app_id)
-        if self.monitor is not None and not self.monitor.try_admit():
-            raise Rejected(f"proxy {self.name} over admissible rate")
-        base = WorkflowMessage.new(app_id=app_id, payload=payload,
-                                   stage=entrances[0][1])
-        for stage, idx, instances in entrances:
-            if self.router.send(instances, base.for_stage(idx),
-                                rr_key=("entrance", app_id, stage)) is None:
-                self._mark_dropped(base.uid_hex)
-                self.complete()  # never (fully) entered the pipeline
-                raise Rejected(f"entrance ring full for stage {stage!r}")
-        return base.uid_hex
+        with span("onepiece.proxy.submit") as sp:
+            entrances = self._entrances(app_id)
+            if self.monitor is not None and not self.monitor.try_admit():
+                raise Rejected(f"proxy {self.name} over admissible rate")
+            base = WorkflowMessage.new(app_id=app_id, payload=payload,
+                                       stage=entrances[0][1])
+            if sp:
+                sp.set_metadata(uid=base.uid_hex)
+            for stage, idx, instances in entrances:
+                if self.router.send(instances, base.for_stage(idx),
+                                    rr_key=("entrance", app_id, stage)) is None:
+                    self._mark_dropped(base.uid_hex)
+                    self.complete()  # never (fully) entered the pipeline
+                    raise Rejected(f"entrance ring full for stage {stage!r}")
+            return base.uid_hex
 
     def submit_many(self, app_id: int, payloads: List[Any]) -> List[str]:
         """Batched admission: one doorbell-batched ring append per entrance

@@ -31,12 +31,35 @@ One process-wide instance (``profiler()``) is shared by the transport
 and cluster layers, mirroring how ``lock_stats_snapshot`` feeds
 ``WorkflowSet.transport_stats()`` — which surfaces ``snapshot()`` as
 ``ChannelStats.latency`` when the profiler is enabled.
+
+A continuous stage (the decode half of ``llm_disagg``) parks a message
+when its stage fn is called and finishes it many ticks later, so for it
+``fn_start`` is the moment the stage took the request into its running
+batch (``admit``) and ``fn_end`` the tick that finished it: ``sched`` is
+the wait for a slot, ``stage_fn`` the residence, ``deliver`` the delivery.
+
+Trace spans
+-----------
+``span(name, **args)`` puts a named interval on the host plane of a
+running ``jax.profiler`` trace, on one clock with the device's ops; the
+benchmark's traced runs read them (PERF.md lists every name and the
+metric that reads it).  While no trace is recorded, ``span`` returns one
+shared no-op context: arguments at a call site are attribute reads, and
+anything dearer is set after entry with ``sp.set_metadata`` under
+``if sp:`` (the no-op context is falsy), so nothing is computed.  Spans
+never sync with the device and never go inside jitted code.  Every span
+of a request carries its ``uid`` (a batch span its ``uids``); spans of
+one thread nest.
 """
 from __future__ import annotations
 
 import threading
 import time
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, Iterable, List, Optional, Tuple
+
+from jaxlib import _profiler as _jaxlib_profiler  # jax's own TraceMe
+
+_TraceMe = _jaxlib_profiler.TraceMe
 
 EVENTS: Tuple[str, ...] = (
     "enqueue", "dequeue", "dispatch", "fn_start", "fn_end", "delivered",
@@ -79,6 +102,8 @@ class LatencyProfiler:
         self._open: Dict[Tuple[str, int], List[Optional[float]]] = {}
         # stage label -> phase name -> samples (seconds); guarded_by: _mu
         self._samples: Dict[str, Dict[str, List[float]]] = {}
+        # uid_hex -> when a continuous stage admitted it; guarded_by: _mu
+        self._admitted: Dict[str, float] = {}
         self.folded = 0       # completed spans; guarded_by: _mu
         self.discarded = 0    # samples beyond max_samples_per_phase
 
@@ -93,6 +118,7 @@ class LatencyProfiler:
         with self._mu:
             self._open.clear()
             self._samples.clear()
+            self._admitted.clear()
             self.folded = 0
             self.discarded = 0
 
@@ -123,6 +149,21 @@ class LatencyProfiler:
             if i == len(EVENTS) - 1:  # delivered: fold and close the span
                 del self._open[key]
                 self._fold_locked(label or f"stage{stage_idx}", rec)
+
+    def admit(self, uid_hex: str) -> None:
+        """A continuous stage took ``uid_hex`` into its running batch: the
+        hop's ``fn_start``, which the instance stamps when the request
+        finishes (the stage fn does not know the message's stage index)."""
+        if not self.enabled:
+            return
+        t = time.monotonic()
+        with self._mu:
+            self._admitted[uid_hex] = t
+
+    def admitted(self, uid_hex: str) -> Optional[float]:
+        """Pop the admission time ``admit`` recorded, if any."""
+        with self._mu:
+            return self._admitted.pop(uid_hex, None)
 
     def _fold_locked(self, label: str, rec: List[Optional[float]]) -> None:
         self.folded += 1
@@ -188,3 +229,79 @@ _PROFILER = LatencyProfiler()
 def profiler() -> LatencyProfiler:
     """The process-wide profiler instance (disabled by default)."""
     return _PROFILER
+
+
+# ------------------------------------------------------------ trace spans
+class _Off:
+    """The span while no trace is recorded: enters, exits, takes metadata
+    and does nothing; falsy, so ``if sp:`` skips building arguments."""
+
+    __slots__ = ()
+
+    def __enter__(self) -> "_Off":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        return None
+
+    def __bool__(self) -> bool:
+        return False
+
+    def set_metadata(self, **args) -> None:
+        pass
+
+
+_OFF = _Off()
+
+#: True while a profiler session records (jaxlib's own check, ~20 ns).
+tracing = _TraceMe.is_enabled
+
+
+def span(name: str, **args):
+    """A ``jax.profiler`` trace span named ``name`` (a ``with`` context),
+    or the shared no-op context while no trace is recorded."""
+    if not _TraceMe.is_enabled():
+        return _OFF
+    return _TraceMe(name, **args)
+
+
+def mark(name: str, **args) -> None:
+    """A zero-length span: an instant on the trace, nothing when off."""
+    if _TraceMe.is_enabled():
+        with _TraceMe(name, **args):
+            pass
+
+
+def uids_arg(msgs: Iterable) -> str:
+    """The ``uids`` argument of a batch span: its messages' uids."""
+    return ",".join(m.uid_hex for m in msgs)
+
+
+def nbytes_arg(payloads: Iterable) -> int:
+    """The ``bytes`` argument: what the payloads' arrays hold (KVPages
+    and arrays have ``nbytes``; anything else counts 0)."""
+    return sum(getattr(p, "nbytes", 0) for p in payloads)
+
+
+class _Batch(threading.local):
+    uids = ""
+
+
+_BATCH = _Batch()
+
+
+def enter_batch(msgs: Iterable) -> str:
+    """Note, for this thread, the uids of the batch whose stage span is
+    open, and return them: a stage fn is given payloads, not messages,
+    and spans inside it carry their requests' uids from ``batch_uids``."""
+    _BATCH.uids = uids_arg(msgs)
+    return _BATCH.uids
+
+
+def leave_batch() -> None:
+    _BATCH.uids = ""
+
+
+def batch_uids() -> str:
+    """The uids of the stage batch running on this thread ('' outside)."""
+    return _BATCH.uids

@@ -194,6 +194,14 @@ class DoubleRingBuffer:
         self.stats.consumed += 1
         return raw[ENTRY_HDR_BYTES:], new_hb, hs + 1
 
+    def ready(self) -> bool:
+        """Whether the entry at the head is committed, so that the next
+        ``poll`` returns it (or CORRUPT).  Reads only; consumer side."""
+        _, _, _, hs = self.read_header(self.consumer_id)
+        word = self.fabric.read_u64(self.consumer_id, self.region,
+                                    self._slot_addr(hs))
+        return bool(word & BUSY_BIT)
+
     def poll(self) -> Union[bytes, Corrupt, None]:
         """Wait-free consume of the next entry; None if nothing available.
 

@@ -35,7 +35,9 @@ from repro.analysis.runtime import make_lock
 from repro.cluster.node_manager import StageSpec, WorkflowSpec
 from repro.cluster.workflow_set import WorkflowSet
 from repro.core.batching import PerRequest
+from repro.core import profiling
 from repro.core.messaging import KVPages
+from repro.core.profiling import profiler, span
 from repro.core.streaming import DEFERRED
 from repro.serving.engine import ServingEngine
 
@@ -71,12 +73,19 @@ def make_prefill_fn(engine: ServingEngine) -> Callable[[Any], Any]:
         temps = np.broadcast_to(np.asarray(payload.get("temperature", 0.0)), (n,))
         seeds = np.broadcast_to(np.asarray(payload.get("seed", 0)), (n,))
         logits, cache = engine.prefill(prompts)
-        logits = np.asarray(logits)
-        leaves = [np.asarray(leaf) for leaf in jax.tree_util.tree_leaves(cache)]
+        logits = np.asarray(logits)   # waits for the prefill program
+        with span("onepiece.handoff.pull") as sp:
+            leaves = [np.asarray(leaf)
+                      for leaf in jax.tree_util.tree_leaves(cache)]
+            rows = [[logits[i]] + [np.take(leaf, [i], axis=ax)
+                                   for leaf, ax in zip(leaves, axes)]
+                    for i in range(n)]
+            if sp:
+                sp.set_metadata(uids=profiling.batch_uids(),
+                                bytes=sum(profiling.nbytes_arg(pages)
+                                          for pages in rows))
         out = []
-        for i in range(n):
-            pages = [logits[i]] + [
-                np.take(leaf, [i], axis=ax) for leaf, ax in zip(leaves, axes)]
+        for i, pages in enumerate(rows):
             out.append(KVPages(
                 meta={"prompt": prompts[i].tolist(),
                       "start": int(prompts.shape[1]),
@@ -144,30 +153,46 @@ class ContinuousDecoder:
             return len(self._waiting) + len(self._slots)
 
     def tick(self) -> List[Tuple[str, Any]]:
+        with span("onepiece.decode.tick", seq=self.stats["segments"]):
+            return self._tick()
+
+    def _tick(self) -> List[Tuple[str, Any]]:
         done: List[Tuple[str, np.ndarray]] = []
         partials: List[Tuple[str, np.ndarray]] = []
+        prof = profiler()
         with self._lock:
             while self._free and self._waiting:
                 uid, kv = self._waiting.popleft()
                 slot = self._free.pop()
                 cache1 = jax.tree_util.tree_unflatten(self._treedef, kv.pages[1:])
-                self._state = self.engine.insert_slot(
-                    self._state, slot, cache1, kv.pages[0],
-                    start=kv.meta["start"], seed=kv.meta["seed"],
-                    steps=kv.meta["steps"],
-                    temperature=kv.meta["temperature"])
+                with span("onepiece.decode.insert", uid=uid, slot=slot,
+                          start=kv.meta["start"]) as sp:
+                    if sp:
+                        sp.set_metadata(bytes=profiling.nbytes_arg([kv]))
+                    self._state = self.engine.insert_slot(
+                        self._state, slot, cache1, kv.pages[0],
+                        start=kv.meta["start"], seed=kv.meta["seed"],
+                        steps=kv.meta["steps"],
+                        temperature=kv.meta["temperature"])
+                if prof.enabled:
+                    prof.admit(uid)
                 self._slots[slot] = {"uid": uid, "meta": kv.meta, "toks": []}
                 self.stats["admitted"] += 1
             if not self._slots:
                 return []
             self.stats["max_resident"] = max(self.stats["max_resident"],
                                              len(self._slots))
-            self._state, toks, adv = self.engine.decode_segment(
-                self._state, self.segment_len)
+            with span("onepiece.decode.segment", seq=self.stats["segments"],
+                      k=self.segment_len):
+                self._state, toks, adv = self.engine.decode_segment(
+                    self._state, self.segment_len)
             self.stats["segments"] += 1
             for slot, ent in list(self._slots.items()):
                 fresh = toks[adv[:, slot], slot]
                 if fresh.size:
+                    if not ent["toks"]:
+                        profiling.mark("onepiece.decode.first_token",
+                                       uid=ent["uid"])
                     ent["toks"].extend(int(t) for t in fresh)
                 want = ent["meta"]["steps"]
                 if len(ent["toks"]) >= want:

@@ -9,6 +9,7 @@ doorbell adds no ring-protocol event.
 """
 from __future__ import annotations
 
+import threading
 import time
 
 import numpy as np
@@ -19,6 +20,7 @@ from repro.cluster import StageSpec, WorkflowSet, WorkflowSpec
 from repro.core import DoubleRingBuffer, RdmaFabric, RingProducer
 from repro.core.batching import Coalescer
 from repro.core.profiling import EVENTS, PHASES, LatencyProfiler, profiler
+from repro.core.streaming import DEFERRED
 
 APP = 1
 
@@ -246,6 +248,75 @@ def test_profiler_surfaces_in_transport_stats():
     for phases in stats.latency.values():
         assert "stage_fn" in phases and "ring" in phases
         assert phases["stage_fn"]["n"] >= 4
+
+
+class _OneSlot:
+    """A continuous stage with one slot, in which a request stays for
+    three ticks of ``TICK_S``: the second of two requests waits for it."""
+
+    continuous = True
+    TICK_S = 0.05
+
+    def __init__(self):
+        self.lock = threading.Lock()
+        self.waiting = []
+        self.held = None      # [uid, ticks left]
+
+    def __call__(self, payload, *, uid):
+        with self.lock:
+            self.waiting.append(uid)
+        return DEFERRED
+
+    def pending(self):
+        with self.lock:
+            return len(self.waiting) + (self.held is not None)
+
+    def tick(self):
+        with self.lock:
+            if self.held is None and self.waiting:
+                self.held = [self.waiting.pop(0), 3]
+                profiler().admit(self.held[0])
+            if self.held is None:
+                return []
+        time.sleep(self.TICK_S)
+        with self.lock:
+            self.held[1] -= 1
+            if self.held[1]:
+                return []
+            uid, self.held = self.held[0], None
+        return [(uid, np.float32(1.0))]
+
+    def abandon(self):
+        with self.lock:
+            uids = self.waiting + ([self.held[0]] if self.held else [])
+            self.waiting, self.held = [], None
+        return uids
+
+
+def test_profiler_phases_of_a_continuous_stage():
+    """For a stage that parks requests and finishes them ticks later,
+    ``sched`` is the wait for a slot, ``stage_fn`` the residence from
+    admission to the tick that finished it, ``deliver`` the delivery."""
+    ws, proxy = _simple_ws("contprof", [("slot", _OneSlot())])
+    prof = profiler()
+    prof.reset()
+    prof.enable()
+    try:
+        with ws:
+            uids = [proxy.submit(APP, {"x": np.float32(i)}) for i in range(2)]
+            for u in uids:
+                proxy.wait_result(u, timeout_s=10)
+        phases = ws.transport_stats().latency["slot"]
+    finally:
+        prof.disable()
+        prof.reset()
+    residence = 3 * _OneSlot.TICK_S
+    assert phases["stage_fn"]["n"] == 2
+    assert phases["stage_fn"]["max_us"] < 3 * residence * 1e6
+    assert phases["stage_fn"]["p50_us"] >= 0.9 * residence * 1e6
+    # the second request waited out the first one's residence
+    assert phases["sched"]["max_us"] >= 0.9 * residence * 1e6
+    assert phases["deliver"]["max_us"] < phases["stage_fn"]["p50_us"] / 2
 
 
 # ------------------------------------------------- Wan I2V parity (slow tier)

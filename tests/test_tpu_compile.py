@@ -11,6 +11,7 @@ this file loads the TPU library.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -61,14 +62,23 @@ def _compile(fn, one_chip, *shapes):
     return compiled
 
 
+def _op_names(compiled):
+    """The ops of the compiled program by name, as the device trace names
+    them (``%<name>.<n> = ...``): the benchmark's kernel reducers match the
+    prefixes ``flash_attention`` and ``wkv6``."""
+    return set(re.findall(r"^\s*%([A-Za-z_][\w\-]*?)(?:\.\d+)? = ",
+                          compiled.as_text(), re.M))
+
+
 @pytest.mark.parametrize("seq", [128, 4096])
 def test_flash_attention_compiles_at_qwen3_prefill_widths(one_chip, seq):
     h, kv, d = QWEN3.num_heads, QWEN3.resolved_kv_heads, QWEN3.resolved_head_dim
     bf = jnp.bfloat16
-    _compile(lambda q, k, v: flash_attention(q, k, v, causal=True,
-                                             interpret=False),
-             one_chip, ((4, seq, h, d), bf), ((4, seq, kv, d), bf),
-             ((4, seq, kv, d), bf))
+    compiled = _compile(lambda q, k, v: flash_attention(q, k, v, causal=True,
+                                                        interpret=False),
+                        one_chip, ((4, seq, h, d), bf), ((4, seq, kv, d), bf),
+                        ((4, seq, kv, d), bf))
+    assert any(n.startswith("flash_attention") for n in _op_names(compiled))
 
 
 @pytest.mark.parametrize("cache", ["bfloat16", "int8"])
@@ -101,6 +111,8 @@ def test_ddim_step_compiles_at_wan_full_latent(one_chip, dtype):
 def test_wkv6_compiles_at_rwkv6_7b_widths(one_chip):
     h, kk = RWKV6.num_heads, RWKV6.resolved_head_dim
     seq = ((1, 1024, h, kk), jnp.bfloat16)
-    _compile(lambda r, k, v, w, u, s: wkv6(r, k, v, w, u, s, interpret=False),
-             one_chip, seq, seq, seq, seq, ((h, kk), jnp.bfloat16),
-             ((1, h, kk, kk), jnp.float32))
+    compiled = _compile(
+        lambda r, k, v, w, u, s: wkv6(r, k, v, w, u, s, interpret=False),
+        one_chip, seq, seq, seq, seq, ((h, kk), jnp.bfloat16),
+        ((1, h, kk, kk), jnp.float32))
+    assert any(n.startswith("wkv6") for n in _op_names(compiled))
