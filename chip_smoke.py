@@ -9,8 +9,9 @@ builders ``python -m repro.launch.serve`` uses:
 
   1. qwen3-1.7b at its published widths in bfloat16, seeded random weights:
      8 requests of 128-token prompts and 64 new tokens through Proxy ->
-     WorkflowSet -> prefill -> KVPages over the fabric -> ContinuousDecoder
-     -> result store (8 slots, max_len 256, segment 8, prefill batch 4).
+     WorkflowSet -> prefill -> KVPages handed over on the device ->
+     ContinuousDecoder -> result store (8 slots, max_len 256, segment 8,
+     prefill batch 4).
      Each stream is held to a solo ``engine.generate`` and the prefill
      logits to a float32 forward of the same weights (serve's
      ``check_llm_tokens`` / ``check_prefill_logits``).
@@ -81,7 +82,8 @@ def phase_llm(serve, layers, compile_s) -> list:
     say(f"phase 1 decode slots: admitted={decoder.stats['admitted']} "
         f"segments={decoder.stats['segments']} "
         f"max_resident={decoder.stats['max_resident']}/8; kv shipping "
-        f"{stats.kv_pages} KVPages, {stats.kv_bytes/1e6:.1f} MB")
+        f"{stats.kv_pages} KVPages, {stats.kv_bytes/1e6:.1f} MB, "
+        f"{serve.device_share(stats)} of handoffs kept on the device")
     say(f"phase 1 dispatch while serving: {served}")
     fails += dispatch_failures(served, KNOWN_FALLBACKS)
     fails += serve.check_llm_tokens(engine, reqs, outs, exact=False)

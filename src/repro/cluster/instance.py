@@ -194,19 +194,27 @@ class ResultDeliver:
                     m.stage = idx
             else:
                 out = [msgs[i].for_stage(idx) for i in live]
-            # KV-cache shipments ride the wire ledger: a silent drop of a
-            # bulk writev surfaces only as an undecodable corrupt entry at
-            # the consumer, so the sender records the UID first and the
-            # receiver settles at unpack (§9 stays per-request exact).
-            if self.joins is not None:
-                for m in out:
-                    if isinstance(m.payload, KVPages):
-                        self.joins.track_wire(m.uid_hex)
+            self.track_wire(out)
             n = self._send_edge(hops, out, (app_id, succ))
             for i in live[n:]:
                 ok[i] = False
                 self.mark_dropped(msgs[i].uid_hex)
         return sum(ok)
+
+    def track_wire(self, msgs: List[WorkflowMessage]) -> None:
+        """KV-cache shipments ride the wire ledger: a silent drop of a
+        bulk writev surfaces only as an undecodable corrupt entry at the
+        consumer, so the sender records the UID before the append and the
+        receiver settles at unpack (§9 stays per-request exact).  A device
+        handoff's pages wait in the same entry: its ring entry is the meta
+        alone."""
+        if self.joins is None:
+            return
+        for m in msgs:
+            if isinstance(m.payload, KVPages):
+                self.joins.track_wire(
+                    m.uid_hex,
+                    m.payload.pages if m.payload.on_device else None)
 
     def _send_edge(self, hops: List[str], out: List[WorkflowMessage],
                    rr_key) -> int:
@@ -336,6 +344,8 @@ class WorkflowInstance:
         # Per-topology-epoch (app_id, stage_idx) -> (stage name, fn | None)
         # cache — same exactness argument as ResultDeliver._routes.
         self._stage_cache: tuple = (-1, {})
+        # (epoch, stage, [StageSpec.room gates]) — see _has_room
+        self._gate_cache: tuple = (-1, None, [])
         # Continuous-stage protocol (repro.core.streaming): messages a
         # continuous stage fn absorbed (returned DEFERRED for) — parked
         # under their UID until a scheduler tick emits their result, and
@@ -503,12 +513,10 @@ class WorkflowInstance:
                 self.stats.dropped += 1
                 continue
             try:
-                m = WorkflowMessage.unpack(item)
+                m = self._unpack(item)
             except Exception:
                 self.stats.dropped += 1
                 continue
-            if isinstance(m.payload, KVPages) and self.rd.joins is not None:
-                self.rd.joins.settle_wire(m.uid_hex)
             msgs.append(m)
 
     def _apply_reassignment(self, coalescer: Coalescer) -> None:
@@ -540,9 +548,15 @@ class WorkflowInstance:
             stage = self._stage_name_of(msg)
             peers = [t for t in (self.nm.stage_instances(stage) if stage else [])
                      if t != self.name]
-            if peers and self.rd.router.send(
+            sent = False
+            if peers:
+                self.rd.track_wire([msg])
+                sent = self.rd.router.send(
                     peers, msg, rr_key=("handoff", msg.app_id, msg.stage)
-            ) is not None:
+                ) is not None
+                if not sent and self.rd.joins is not None:
+                    self.rd.joins.settle_wire(msg.uid_hex)  # kept here
+            if sent:
                 self.stats.handoffs += 1
             else:
                 self._dispatch([msg])  # no live peer: run it here, correctly
@@ -633,7 +647,7 @@ class WorkflowInstance:
         while not self._stop.is_set():
             self._apply_reassignment(coalescer)
             cont_busy = self._pump_continuous()
-            msg = self._receive()
+            msg = self._receive() if self._has_room() else None
             if msg is None:
                 if cont_busy:
                     continue  # slots still decoding: tick again, don't park
@@ -678,6 +692,18 @@ class WorkflowInstance:
             self.stats.dropped += len(batch)
             self._mark_dropped_msgs(batch)
 
+    def _has_room(self) -> bool:
+        """False while a ``room`` gate of the stage this instance runs
+        (``StageSpec.room``) reports no room: the inbox is then left
+        unread.  The gates are cached per topology epoch."""
+        epoch, stage = self.nm.topology_version(), self._stage
+        cache = self._gate_cache
+        if cache[:2] != (epoch, stage):
+            gates = [s.room for s in self.nm.stage_specs(stage)
+                     if s.room is not None] if stage else []
+            cache = self._gate_cache = (epoch, stage, gates)
+        return all(room() > 0 for room in cache[2])
+
     def _receive(self):
         """One inbox poll: the decoded message, None when the inbox is
         empty, or ``_DROP`` for an entry that was counted dropped.  While
@@ -701,15 +727,28 @@ class WorkflowInstance:
             self.stats.dropped += 1  # checksum-failed entry, no retry (§9)
             return _DROP
         try:
-            msg = WorkflowMessage.unpack(item)
+            msg = self._unpack(item)
         except Exception:
             self.stats.dropped += 1
             return _DROP
-        if isinstance(msg.payload, KVPages) and self.rd.joins is not None:
-            self.rd.joins.settle_wire(msg.uid_hex)  # KV ship arrived
         prof = profiler()
         if prof.enabled:
             prof.stamp(msg.uid_hex, msg.stage, "dequeue")
+        return msg
+
+    def _unpack(self, item) -> WorkflowMessage:
+        """Decode an inbox entry.  A KV shipment settles its wire-ledger
+        entry, and a device handoff takes its pages back from it; one
+        whose pages a tombstone or an expiry dropped first raises
+        ``LookupError``."""
+        msg = WorkflowMessage.unpack(item)
+        if isinstance(msg.payload, KVPages):
+            joins = self.rd.joins
+            pages = None if joins is None else joins.settle_wire(msg.uid_hex)
+            if msg.payload.pages is None:
+                if pages is None:
+                    raise LookupError(f"KV handoff {msg.uid_hex} has no pages")
+                msg.payload.pages = pages
         return msg
 
     # ------------------------------------------------------------- workers
@@ -834,6 +873,9 @@ class WorkflowInstance:
 
     def _worker_loop(self, widx: int) -> None:
         while not self._stop.is_set():
+            if not self._has_room():  # the stage's gate holds the queue too
+                self._stop.wait(self.poll_interval_s)
+                continue
             try:
                 msgs = self._queue.get(timeout=self.poll_interval_s)
             except queue.Empty:
@@ -848,7 +890,11 @@ class WorkflowInstance:
                 self.rd.mark_dropped(m.uid_hex)
             elif r is DEFERRED:
                 # absorbed by a continuous stage: park under the UID (not
-                # processed yet — the pump delivers and counts it later)
+                # processed yet — the pump delivers and counts it later).
+                # The stage owns the payload now; the parked message keeps
+                # only its identity, so a shipment's pages are freed once
+                # the stage is done with them, not when the request ends.
+                m.payload = None
                 with self._cont_lock:
                     self._deferred[m.uid_hex] = m
                 self._doorbell.set()  # wake a parked scheduler to pump

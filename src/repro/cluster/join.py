@@ -29,7 +29,11 @@ decodes no UID — is ``track_wire``'d by the sender before the append and
 ``settle_wire``'d by the receiver at unpack.  A shipment that never
 settles stays in ``pending_uids`` (reconciled as dead after a quiesce)
 and is tombstoned by the TTL sweep, so even a silently dropped KV-cache
-ship keeps ``submitted == stored ∪ dead_uids()``.
+ship keeps ``submitted == stored ∪ dead_uids()``.  A device-resident KV
+handoff's pages wait in the same entry — the ring carries its meta alone
+— and leave with it: ``settle_wire`` hands them to the receiver, and a
+tombstone, an expiry or ``release_wire_pages`` (the set's stop) drops
+them, which frees them.
 
 State is bounded like the transient database's: stranded partials (their
 sibling was lost with no decodable UID) and tombstones both expire after
@@ -112,8 +116,9 @@ class JoinTable:
         #: ``dropped_snapshot()`` — the raw set mutates under you.
         self.dropped_uids: Set[str] = set()  # guarded_by: _lock
         self._dropped_at: Dict[str, float] = {}  # guarded_by: _lock
-        #: wire ledger — tracked bulk shipments awaiting receiver settle
-        self._wire: Dict[str, float] = {}  # guarded_by: _lock
+        #: wire ledger — tracked bulk shipments awaiting receiver settle:
+        #: uid -> (track time, device pages handed over beside the ring)
+        self._wire: Dict[str, Tuple[float, Any]] = {}  # guarded_by: _lock
         self._last_sweep = clock()
         self.stats = JoinStats()  # guarded_by: _lock
 
@@ -180,7 +185,8 @@ class JoinTable:
         # Wire-ledger expiry tombstones (rather than forgets): a shipment
         # that never settled is a *known* drop — keep the §9 invariant
         # even after the pending window closes.
-        for uid in [u for u, t in self._wire.items() if now - t > self.ttl_s]:
+        for uid in [u for u, (t, _) in self._wire.items()
+                    if now - t > self.ttl_s]:
             del self._wire[uid]
             self.dropped_uids.add(uid)
             self._dropped_at[uid] = now
@@ -262,7 +268,8 @@ class JoinTable:
             first = uid_hex not in self.dropped_uids
             self.dropped_uids.add(uid_hex)
             self._dropped_at[uid_hex] = self.clock()
-            self._wire.pop(uid_hex, None)  # a dead request owes no settle
+            # a dead request owes no settle, and holds no pages
+            self._wire.pop(uid_hex, None)
             for key in [k for k in self._pending if k[2] == uid_hex]:
                 parts = self._pending.pop(key)
                 del self._pending_at[key]
@@ -272,23 +279,38 @@ class JoinTable:
         return first
 
     # ------------------------------------------------------------ wire ledger
-    def track_wire(self, uid_hex: str) -> None:
+    def track_wire(self, uid_hex: str, pages: Any = None) -> None:
         """Sender side: record a bulk shipment (e.g. a KV-cache ship)
         whose silent wire loss the receiver could only observe as a
         corrupt ring entry with no decodable UID.  Until the receiver
-        settles it, the UID counts as pending (→ dead after a quiesce)."""
+        settles it, the UID counts as pending (→ dead after a quiesce).
+        ``pages`` are what travels beside the ring entry (a device KV
+        handoff's arrays), held here until the receiver takes them."""
         with self._lock:
             if uid_hex not in self.dropped_uids:
-                self._wire.setdefault(uid_hex, self.clock())
+                self._wire.setdefault(uid_hex, (self.clock(), pages))
 
-    def settle_wire(self, uid_hex: str) -> None:
-        """Receiver side: the tracked shipment arrived intact."""
+    def settle_wire(self, uid_hex: str) -> Any:
+        """Receiver side: the tracked shipment arrived intact.  Returns
+        the pages tracked with it (None if none, or if a tombstone or an
+        expiry dropped them first)."""
         with self._lock:
-            self._wire.pop(uid_hex, None)
+            return self._wire.pop(uid_hex, (None, None))[1]
 
     def wire_pending(self) -> int:
         with self._lock:
             return len(self._wire)
+
+    def wire_pages(self) -> int:
+        """Tracked shipments whose pages the ledger still holds."""
+        with self._lock:
+            return sum(pages is not None for _, pages in self._wire.values())
+
+    def release_wire_pages(self) -> None:
+        """Drop the pages of every unsettled shipment (a stopping set
+        delivers none of them); the uids stay pending, reconciled dead."""
+        with self._lock:
+            self._wire = {u: (t, None) for u, (t, _) in self._wire.items()}
 
     # ------------------------------------------------------------- queries
     def dropped_snapshot(self) -> Set[str]:

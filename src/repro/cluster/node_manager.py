@@ -56,6 +56,10 @@ class StageSpec:
     # chain it always was.  [] = entrance stage (fed by the proxy); two or
     # more names = fan-in stage assembled in the JoinTable.
     deps: Optional[List[str]] = None
+    # Admission gate: while it returns <= 0, an instance running this stage
+    # leaves its inbox unread, so what would pass a bound waits upstream
+    # (in the ring, then in the sender's) instead of being taken on.
+    room: Optional[Callable[[], int]] = None
 
 
 @dataclass
@@ -237,6 +241,12 @@ class NodeManager:
                 if s.name == stage:
                     return s
             raise KeyError(f"stage {stage} not in workflow {app_id}")
+
+    def stage_specs(self, stage: str) -> List[StageSpec]:
+        """Every registered workflow's spec of the stage named ``stage``."""
+        with self._lock:
+            return [s for wf in self.workflows.values() for s in wf.stages
+                    if s.name == stage]
 
     def stage_name(self, app_id: int, stage_idx: int) -> str:
         """Resolve a message's stage *index* to its stage name.  This is the

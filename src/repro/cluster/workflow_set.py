@@ -135,6 +135,10 @@ class WorkflowSet:
             inst.join()
         for inst in self.instances.values():
             inst.drain_terminal()
+        # A device KV handoff whose ring entry was lost never settles: its
+        # pages wait in the wire ledger until the TTL sweep or, here, the
+        # stop.
+        self.joins.release_wire_pages()
         # Durability barrier: every queued join-mirror op has reached the
         # database replicas before the set reports itself stopped.
         self.joins.flush_mirror()

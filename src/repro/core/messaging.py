@@ -13,7 +13,7 @@ import struct
 import time
 import uuid as uuidlib
 from dataclasses import dataclass, field
-from typing import Any, Dict, List, Tuple, Union
+from typing import Any, Dict, List, Optional, Tuple, Union
 
 import ml_dtypes  # noqa: F401 -- registers bfloat16/float8/int4 with numpy
 import numpy as np
@@ -27,6 +27,7 @@ _KIND_BYTES = 0
 _KIND_TENSOR = 1
 _KIND_JSONTREE = 2
 _KIND_KVPAGES = 3
+_KIND_KVDEVICE = 4
 
 _KEEP = object()  # for_stage default: carry this message's payload unchanged
 
@@ -42,19 +43,33 @@ class KVPages:
     ``pages`` holds the cache tree's leaves in ``jax.tree`` flatten order —
     one page per leaf, each a B=1 slice along that leaf's batch axis.
     ``meta`` is the JSON-safe decode plan riding along (prompt tokens,
-    start index, steps, temperature, seed).  The wire form is one gather
-    list — header, meta blob, then each page's raw bytes behind a ``<Q>``
-    length — so a whole cache ships as ONE ``RdmaFabric.writev`` with no
-    Python-side concatenation, and decodes back to zero-copy views over
-    the ring slot.
+    start index, steps, temperature, seed).
+
+    Two placements, chosen by what the pages are:
+
+      * host pages (``np.ndarray``): the wire form is one gather list —
+        header, meta blob, then each page's raw bytes behind a ``<Q>``
+        length — so a whole cache ships as ONE ``RdmaFabric.writev`` with
+        no Python-side concatenation, and decodes back to zero-copy views
+        over the ring slot;
+      * device pages (``jax.Array``, ``on_device``): the arrays stay where
+        they are.  The ring entry carries the meta alone and decodes with
+        ``pages=None``; the pages wait in the sender's wire ledger
+        (``JoinTable.track_wire``) until the receiver settles the uid and
+        takes them back.
     """
 
     meta: Dict[str, Any]
-    pages: List[np.ndarray] = field(default_factory=list)
+    pages: Optional[List[Any]] = field(default_factory=list)
 
     @property
     def nbytes(self) -> int:
         return sum(p.nbytes for p in self.pages)
+
+    @property
+    def on_device(self) -> bool:
+        """Whether the pages are device arrays (not host ``np.ndarray``)."""
+        return bool(self.pages) and not isinstance(self.pages[0], np.ndarray)
 
 
 def _tensor_view(x: np.ndarray) -> Buf:
@@ -88,6 +103,10 @@ def _encode_payload_parts(payload: Payload) -> List[Buf]:
                            "shape": payload.shape}).encode()
         return [struct.pack("<BI", _KIND_TENSOR, len(meta)), meta,
                 _tensor_view(payload)]
+    if isinstance(payload, KVPages) and payload.on_device:
+        # the pages travel beside the ring, in the wire ledger
+        meta = json.dumps({"meta": payload.meta}).encode()
+        return [struct.pack("<BI", _KIND_KVDEVICE, len(meta)), meta]
     if isinstance(payload, KVPages):
         pages = [np.asarray(p) for p in payload.pages]
         meta = json.dumps({
@@ -137,7 +156,8 @@ def _encode_payload(payload: Payload) -> bytes:
 
 def _decode_payload(raw: Buf) -> Payload:
     """Decode from any buffer; tensor leaves are zero-copy views into `raw`
-    (read-only, exactly like the seed's frombuffer-over-bytes behavior)."""
+    (read-only, exactly like the seed's frombuffer-over-bytes behavior).
+    A device KV handoff decodes to its meta with ``pages=None``."""
     mv = memoryview(raw)
     kind = mv[0]
     if kind == _KIND_BYTES:
@@ -188,6 +208,10 @@ def _decode_payload(raw: Buf) -> Payload:
                 dtype=np.dtype(spec["dtype"])).reshape(spec["shape"]))
             off += blen
         return KVPages(meta=head["meta"], pages=pages)
+    if kind == _KIND_KVDEVICE:
+        (mlen,) = struct.unpack_from("<I", mv, 1)
+        head = json.loads(bytes(mv[5 : 5 + mlen]))
+        return KVPages(meta=head["meta"], pages=None)
     raise ValueError(f"bad payload kind {kind}")
 
 

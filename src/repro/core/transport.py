@@ -46,9 +46,11 @@ class ChannelStats:
     batches: int = 0
     # KV-page shipments (the prefill->decode edge of llm_disagg,
     # docs/disaggregation.md): messages whose payload is a KVPages cache
-    # shipment, and the raw cache bytes inside them
+    # shipment, and the raw cache bytes inside them, whatever the
+    # placement; of those, the handoffs whose pages stayed on the device
     kv_pages: int = 0
     kv_bytes: int = 0
+    kv_device_handoffs: int = 0
     # stage-fn / tick() exceptions across the set's instances, and the
     # first one's traceback; filled by WorkflowSet.transport_stats()
     stage_errors: int = 0
@@ -71,6 +73,8 @@ class ChannelStats:
             batches=self.batches + other.batches,
             kv_pages=self.kv_pages + other.kv_pages,
             kv_bytes=self.kv_bytes + other.kv_bytes,
+            kv_device_handoffs=(self.kv_device_handoffs
+                                + other.kv_device_handoffs),
             stage_errors=self.stage_errors + other.stage_errors,
             first_error=self.first_error or other.first_error,
             lock_stats={**self.lock_stats, **other.lock_stats},
@@ -131,6 +135,7 @@ class Channel:
                 with self._lock:
                     self.stats.kv_pages += 1
                     self.stats.kv_bytes += msg.payload.nbytes
+                    self.stats.kv_device_handoffs += msg.payload.on_device
             prof = profiler()
             if prof.enabled:
                 prof.stamp(msg.uid_hex, msg.stage, "enqueue")
@@ -164,6 +169,7 @@ class Channel:
             self.stats.bytes_sent += nbytes
             self.stats.kv_pages += len(kv)
             self.stats.kv_bytes += sum(p.nbytes for p in kv)
+            self.stats.kv_device_handoffs += sum(p.on_device for p in kv)
         prof = profiler()
         if prof.enabled:
             t = time.monotonic()

@@ -21,9 +21,9 @@ Workflows (docs/workflows.md):
   * a2v   — audio-to-video: asr -> (llm -> text_encode) ∥ image_encode
             -> diffusion -> vae_decode, a nested two-branch DAG;
   * llm   — disaggregated prefill/decode LLM serving
-            (docs/disaggregation.md): jitted prefill ships KV caches as
-            KVPages over the fabric into a continuous-batching decode
-            stage.  ``--llm-size reduced`` (float32, CPU-sized) checks the
+            (docs/disaggregation.md): jitted prefill hands KV caches as
+            KVPages (kept on the device: both stages run one engine) to a
+            continuous-batching decode stage.  ``--llm-size reduced`` (float32, CPU-sized) checks the
             tokens bit-identical to solo generate; ``published`` (the
             config's own widths and dtype) holds them to solo generate
             within the bf16 tolerance of ``check_llm_tokens``.
@@ -395,6 +395,11 @@ def check_prefill_logits(engine, prompts: np.ndarray,
     return worst, fails
 
 
+def device_share(stats) -> str:
+    """``kv_device_handoffs / kv_pages`` of a set's transport stats."""
+    return f"{stats.kv_device_handoffs}/{stats.kv_pages}"
+
+
 def run_llm(args) -> int:
     """--workflow llm: the two-stage llm_disagg DAG end-to-end.
 
@@ -432,7 +437,8 @@ def run_llm(args) -> int:
           f"segments={decoder.stats['segments']} "
           f"max_resident={decoder.stats['max_resident']}/{args.llm_slots}")
     print(f"kv shipping: {stats.kv_pages} KVPages messages, "
-          f"{stats.kv_bytes/1e6:.1f} MB of cache over the fabric")
+          f"{stats.kv_bytes/1e6:.1f} MB of cache, "
+          f"{device_share(stats)} of handoffs kept on the device")
     if args.profile_latency:
         print_latency()
     return report(fails)

@@ -5,9 +5,10 @@ Generation is split into the two stages of the ``llm_disagg`` workflow:
   * **prefill** — one jitted ``ServingEngine.prefill`` over the prompt
     (batched under the coalescer when the instance runs ``max_batch > 1``).
     Each request's KV cache leaves are sliced out along their per-leaf
-    batch axes (``engine.batch_axes``) and shipped downstream as
-    :class:`~repro.core.messaging.KVPages` — one gather list, one
-    ``RdmaFabric.writev``, zero intermediate copies.
+    batch axes (``engine.batch_axes``) on the device and handed downstream
+    as :class:`~repro.core.messaging.KVPages` of device arrays: the ring
+    carries the meta, the wire ledger the arrays, and the cache never
+    leaves the device.
 
   * **decode** — a :class:`ContinuousDecoder`, a *continuous* stage
     (``repro.core.streaming``): requests join and leave a running slot
@@ -44,11 +45,11 @@ from repro.serving.engine import ServingEngine
 APP_LLM_DISAGG = 7
 
 
-def shipment_wire_bytes(engine: ServingEngine) -> int:
-    """Ring bytes one KVPages shipment can take: its pages plus headers,
-    per-page lengths and the JSON meta, whose prompt ids (at most
-    ``max_len`` of them, <= 12 characters each) dominate."""
-    return engine.shipment_bytes + 4096 + 12 * engine.max_len
+def meta_wire_bytes(engine: ServingEngine) -> int:
+    """Ring bytes one KV handoff entry can take: headers and the JSON meta,
+    whose prompt ids (at most ``max_len`` of them, <= 12 characters each)
+    dominate.  The pages travel beside the ring."""
+    return 4096 + 12 * engine.max_len
 
 
 def make_prefill_fn(engine: ServingEngine) -> Callable[[Any], Any]:
@@ -60,10 +61,18 @@ def make_prefill_fn(engine: ServingEngine) -> Callable[[Any], Any]:
     lifts numeric scalars to vectors) — and returns one ``KVPages`` per
     request: page 0 is the last-token logits row, pages 1.. are the cache
     leaves in ``jax.tree`` flatten order, each the request's B=1 slice
-    along that leaf's batch axis.  A ``PerRequest`` wrapper keeps the
-    per-request pages out of ``unstack_payload``'s generic row-slicing.
+    along that leaf's batch axis.  The pages are device arrays: a batch of
+    one hands its cache tree over as it is, a larger batch takes each
+    request's slice on the device (one jitted slice per leaf).  A
+    ``PerRequest`` wrapper keeps the per-request pages out of
+    ``unstack_payload``'s generic row-slicing.
     """
-    axes = [int(a) for a in jax.tree_util.tree_leaves(engine.batch_axes)]
+
+    @jax.jit
+    def row(logits, cache, i):
+        return logits[i], jax.tree.map(
+            lambda leaf, ax: jax.lax.dynamic_slice_in_dim(leaf, i, 1, ax),
+            cache, engine.batch_axes)
 
     def prefill_fn(payload: Dict[str, Any]):
         prompts = np.asarray(payload["prompt"], np.int32)
@@ -73,13 +82,15 @@ def make_prefill_fn(engine: ServingEngine) -> Callable[[Any], Any]:
         temps = np.broadcast_to(np.asarray(payload.get("temperature", 0.0)), (n,))
         seeds = np.broadcast_to(np.asarray(payload.get("seed", 0)), (n,))
         logits, cache = engine.prefill(prompts)
-        logits = np.asarray(logits)   # waits for the prefill program
-        with span("onepiece.handoff.pull") as sp:
-            leaves = [np.asarray(leaf)
-                      for leaf in jax.tree_util.tree_leaves(cache)]
-            rows = [[logits[i]] + [np.take(leaf, [i], axis=ax)
-                                   for leaf, ax in zip(leaves, axes)]
-                    for i in range(n)]
+        logits.block_until_ready()    # waits for the prefill program
+        with span("onepiece.handoff.pull", placement="device") as sp:
+            if n == 1:
+                rows = [[logits[0]] + jax.tree_util.tree_leaves(cache)]
+            else:
+                rows = []
+                for i in range(n):
+                    lg, c = row(logits, cache, np.int32(i))
+                    rows.append([lg] + jax.tree_util.tree_leaves(c))
             if sp:
                 sp.set_metadata(uids=profiling.batch_uids(),
                                 bytes=sum(profiling.nbytes_arg(pages)
@@ -137,7 +148,7 @@ class ContinuousDecoder:
         self._slots: Dict[int, Dict[str, Any]] = {}   # slot -> request entry
         self._free: List[int] = list(range(max_slots - 1, -1, -1))
         self.stats = {"admitted": 0, "completed": 0, "segments": 0,
-                      "abandoned": 0, "max_resident": 0}
+                      "abandoned": 0, "max_resident": 0, "max_parked": 0}
 
     # ------------------------------------------------------------- protocol
     def __call__(self, payload: Any, *, uid: str):
@@ -146,11 +157,20 @@ class ContinuousDecoder:
                 f"decode stage expects KVPages, got {type(payload).__name__}")
         with self._lock:
             self._waiting.append((uid, payload))
+            self.stats["max_parked"] = max(self.stats["max_parked"],
+                                           len(self._waiting))
         return DEFERRED
 
     def pending(self) -> int:
         with self._lock:
             return len(self._waiting) + len(self._slots)
+
+    def parked(self) -> int:
+        """Shipments waiting for a slot, each holding a whole cache.  Read
+        without the lock, which a tick holds for a whole decode segment:
+        the prefill's gate asks from its own thread and must not wait for
+        one (``len`` of a deque is atomic)."""
+        return len(self._waiting)
 
     def tick(self) -> List[Tuple[str, Any]]:
         with span("onepiece.decode.tick", seq=self.stats["segments"]):
@@ -249,14 +269,21 @@ def build_llm_disagg_set(
 ) -> Tuple[WorkflowSet, "ContinuousDecoder"]:
     """Wire a two-stage llm_disagg Workflow Set around one engine.
 
-    Each message into a decode inbox is a whole KV cache, so that ring is
-    sized from the engine's per-request shipment: it holds a full slot
-    batch plus one prefill batch of shipments in flight (the prefill
-    inbox carries prompts only and keeps the instance default).  The
-    decoder publishes per-segment partials to the set's replicated
-    database and purges them on completion.  Returns ``(set, decoder)`` —
-    the decoder is shared by every decode instance, so all of them feed
-    one slot batch.
+    Both stages run the engine in this process, so each KV cache stays on
+    the device from prefill to slot insert; a decode inbox entry is its
+    meta alone.  What is on its way or parked waiting for a slot holds a
+    whole cache in device memory, so it is bounded at ``in_flight``: a
+    full slot batch plus one prefill batch per prefill instance.  Two
+    ``StageSpec.room`` gates hold it there without a drop — the decode
+    instance reads its inbox only while fewer than ``in_flight`` caches
+    are parked, and a prefill instance takes a prompt only while parked
+    and travelling caches leave room for one more batch; the excess waits
+    as prompts in the prefill inbox.  The decode ring holds ``in_flight``
+    entries (the prefill inbox keeps the instance default).  The decoder
+    publishes per-segment partials to the set's replicated database and
+    purges them on completion.  Returns ``(set, decoder)`` — the decoder
+    is shared by every decode instance, so all of them feed one slot
+    batch.
     """
     ws = WorkflowSet(name, control_loop=control_loop)
     db = ws.database
@@ -270,19 +297,27 @@ def build_llm_disagg_set(
     decoder = ContinuousDecoder(engine, max_slots=max_slots,
                                 segment_len=segment_len,
                                 publish=publish, retract=retract)
+    in_flight = max_slots + n_prefill * prefill_batch
+
+    def decode_room() -> int:
+        return in_flight - decoder.parked()
+
+    def prefill_room() -> int:
+        return max_slots + 1 - decoder.parked() - ws.joins.wire_pages()
+
     ws.register_workflow(WorkflowSpec(APP_LLM_DISAGG, "llm_disagg", [
         StageSpec("prefill", fn=make_prefill_fn(engine),
-                  exec_time_s=prefill_time_s, deps=[]),
+                  exec_time_s=prefill_time_s, deps=[], room=prefill_room),
         StageSpec("decode", fn=decoder, exec_time_s=decode_time_s,
-                  deps=["prefill"]),
+                  deps=["prefill"], room=decode_room),
     ]))
     for i in range(n_prefill):
         ws.add_instance(f"prefill{i}", stage="prefill",
                         max_batch=prefill_batch, max_wait_s=max_wait_s,
                         pad_to_full=prefill_batch > 1, inline=inline)
-    ring_bytes = (max_slots + prefill_batch) * shipment_wire_bytes(engine)
     for i in range(n_decode):
         ws.add_instance(f"decode{i}", stage="decode", max_batch=1,
-                        inline=inline, ring_bytes=ring_bytes)
+                        inline=inline, ring_slots=in_flight,
+                        ring_bytes=in_flight * meta_wire_bytes(engine))
     ws.add_proxy("p0")
     return ws, decoder

@@ -188,6 +188,26 @@ def test_stage_error_counted_with_traceback():
     assert uid in ws.dead_uids()
 
 
+@pytest.mark.parametrize("inline", [True, False])
+def test_stage_room_gate_holds_requests_upstream(inline):
+    """While a stage's ``room`` gate reads 0 its instance takes nothing,
+    whether it runs the stage inline or on a worker: the request waits in
+    the inbox and runs once the gate opens."""
+    gate = {"room": 0}
+    ws = WorkflowSet("gate", control_loop=False)
+    ws.register_workflow(WorkflowSpec(1, "wf", [
+        StageSpec("s", fn=lambda x: x + 1.0, room=lambda: gate["room"])]))
+    ws.add_instance("s0", stage="s", inline=inline)
+    p = ws.add_proxy("p0")
+    with ws:
+        uid = p.submit(1, np.float32(1.0))
+        time.sleep(0.2)
+        assert p.poll_result(uid) is None
+        assert ws.instances["gate.s0"].stats.processed == 0
+        gate["room"] = 1
+        assert p.wait_result(uid, timeout_s=5) == 2.0
+
+
 def test_instance_sharing_across_workflows():
     """§8.3: two apps share the 'mul' stage instances, diverge afterwards."""
     ws = WorkflowSet("share")

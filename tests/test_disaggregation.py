@@ -24,6 +24,7 @@ from repro.serving import (
     ContinuousDecoder,
     ServingEngine,
     build_llm_disagg_set,
+    make_prefill_fn,
 )
 
 
@@ -63,11 +64,38 @@ def _quiesce(ws, proxy, uids, timeout_s: float = 30.0):
     return results
 
 
+FAMILIES = {"qwen3": "qwen3-1.7b", "rwkv6": "rwkv6-7b"}
+
+
+def _engine(arch):
+    cfg = dataclasses.replace(get_config(arch).reduced(), dtype="float32")
+    return ServingEngine(cfg, max_len=64)
+
+
 @pytest.fixture(scope="module")
 def engine():
-    cfg = dataclasses.replace(get_config("qwen3-1.7b").reduced(),
-                              dtype="float32")
-    return ServingEngine(cfg, max_len=64)
+    return _engine(FAMILIES["qwen3"])
+
+
+@pytest.fixture(scope="module")
+def engines(engine):
+    """``family -> engine``: a KV family (qwen3) and a recurrent-state
+    family (rwkv6)."""
+    made = {"qwen3": engine}
+
+    def get(family):
+        if family not in made:
+            made[family] = _engine(FAMILIES[family])
+        return made[family]
+    return get
+
+
+def _assert_handoffs(ws):
+    """Every KV handoff stayed on the device, and the wire ledger holds
+    no pages: no device cache outlives its request."""
+    stats = ws.transport_stats()
+    assert stats.kv_device_handoffs == stats.kv_pages
+    assert ws.joins.wire_pages() == 0
 
 
 def _payload(engine, i, steps=8, temperature=0.7):
@@ -84,9 +112,12 @@ def _solo(engine, payload):
 
 
 # ============================================================ happy path
-def test_disagg_end_to_end_matches_solo_generate(engine):
+@pytest.mark.parametrize("family", ["qwen3", "rwkv6"])
+def test_disagg_end_to_end_matches_solo_generate(engines, family):
     """Two-stage prefill→decode over the fabric, three requests sharing
-    the slot batch: every result is bit-identical to a solo generate."""
+    the slot batch: every result is bit-identical to a solo generate, the
+    KV caches handed over on the device."""
+    engine = engines(family)
     ws, dec = build_llm_disagg_set(engine, name="e2e", max_slots=2,
                                    segment_len=3)
     payloads = [_payload(engine, i) for i in range(3)]
@@ -102,6 +133,28 @@ def test_disagg_end_to_end_matches_solo_generate(engine):
     # the KV ship was accounted as KV pages on the transport
     stats = ws.transport_stats()
     assert stats.kv_pages >= 3 and stats.kv_bytes > 0
+    assert stats.kv_bytes == stats.kv_pages * engine.shipment_bytes
+    _assert_handoffs(ws)
+
+
+def test_device_handoff_slices_a_prefill_batch_on_the_device(engine):
+    """A coalesced prefill batch of two hands each request its own B=1
+    device slice: four requests, bit-identical to solo, none copied out."""
+    ws, dec = build_llm_disagg_set(engine, name="batch2", max_slots=2,
+                                   segment_len=3, prefill_batch=2,
+                                   max_wait_s=0.2)
+    payloads = [_payload(engine, i, temperature=0.0) for i in range(4)]
+    with ws:
+        p = ws.proxies[0]
+        uids = p.submit_many(APP_LLM_DISAGG, payloads)
+        res = [p.wait_result(u, timeout_s=60) for u in uids]
+    for pl, r in zip(payloads, res):
+        np.testing.assert_array_equal(r, _solo(engine, pl))
+    stats = ws.transport_stats()
+    assert stats.kv_pages == 4 and stats.kv_device_handoffs == 4
+    assert stats.kv_bytes == 4 * engine.shipment_bytes
+    assert ws.instances["batch2.prefill0"].stats.batches < 4  # coalesced
+    assert ws.joins.wire_pages() == 0
 
 
 def test_disagg_partial_streaming(engine):
@@ -131,24 +184,26 @@ def test_disagg_partial_streaming(engine):
 
 
 def test_decode_ring_holds_the_shipments_in_flight(engine):
-    """The decode inbox is sized from the engine's per-request shipment,
-    so no configuration drops every KV ship on ring capacity."""
+    """The decode inbox is sized from what travels in it, a handoff's meta
+    alone, for every cache that may be on its way or parked — so no
+    configuration drops a KV handoff on ring capacity."""
     import jax
 
-    from repro.serving.disagg import shipment_wire_bytes
+    from repro.serving.disagg import meta_wire_bytes
 
     logits, cache = engine.prefill(np.zeros((1, 4), np.int32))
-    pages = [np.asarray(logits)[0]] + [np.asarray(x)
-                                       for x in jax.tree.leaves(cache)]
+    pages = [logits[0]] + jax.tree.leaves(cache)
     assert engine.shipment_bytes == sum(p.nbytes for p in pages)
     ws, _ = build_llm_disagg_set(engine, name="ring", max_slots=3,
                                  prefill_batch=2)
-    ring = ws.instances["ring.decode0"].inbox.buf_size
-    assert ring >= (3 + 2) * engine.shipment_bytes
+    inbox = ws.instances["ring.decode0"].inbox
     msg = WorkflowMessage.new(APP_LLM_DISAGG, payload=KVPages(
         meta={"prompt": list(range(engine.max_len)), "start": 4,
               "steps": 8, "temperature": 0.0, "seed": 0}, pages=pages))
-    assert sum(len(p) for p in msg.pack_parts()) <= shipment_wire_bytes(engine)
+    entry = sum(len(p) for p in msg.pack_parts())
+    assert inbox.n_slots == 3 + 2
+    assert inbox.buf_size >= (3 + 2) * entry
+    assert entry <= meta_wire_bytes(engine) < engine.shipment_bytes
 
 
 def test_decode_tick_error_fails_the_run(engine, monkeypatch):
@@ -177,17 +232,30 @@ def test_decode_tick_error_fails_the_run(engine, monkeypatch):
 
 
 # ==================================================== fault injection
+def _entry_write(ws, name):
+    """Fault-hook predicate: the write of an entry's bytes (not a ring
+    header or slot word) into ``name``'s inbox — there, the KV handoff's
+    entry."""
+    inbox = ws.instances[name].inbox
+
+    def hits(verb, region, offset):
+        return (verb == "write" and region == inbox.region
+                and offset >= inbox.buf_off)
+    return hits
+
+
 def test_kv_ship_dropped_mid_writev_is_accounted(engine):
-    """The decode-bound KV-page writev is lost on the wire: the consumer
+    """The decode-bound KV entry's writev is lost on the wire: the consumer
     sees only a corrupt ring entry, yet the wire ledger keeps the victim
-    in dead_uids() — submitted == stored ∪ dead, no decode slot stranded."""
+    in dead_uids() — submitted == stored ∪ dead, no decode slot stranded,
+    and the victim's device pages freed when the set stops."""
     ws, dec = build_llm_disagg_set(engine, name="wire", max_slots=2,
                                    segment_len=3)
     state = {"armed": False, "dropped": 0}
+    kv_entry = _entry_write(ws, "wire.decode0")
 
     def hook(client, verb, region, offset, n):
-        if (state["armed"] and verb == "write" and n > 4096
-                and region == "wire.decode0.inbox"):
+        if state["armed"] and kv_entry(verb, region, offset):
             state["armed"] = False
             state["dropped"] += 1
             return False
@@ -217,21 +285,22 @@ def test_kv_ship_dropped_mid_writev_is_accounted(engine):
     # and never occupied (or stranded) a decode slot
     assert dec.pending() == 0
     assert dec.stats["admitted"] == 4
+    _assert_handoffs(ws)
 
 
 @pytest.mark.filterwarnings(
     "ignore::pytest.PytestUnhandledThreadExceptionWarning")
 def test_kv_ship_killed_by_simulated_crash_is_accounted(engine):
     """The prefill worker dies mid-writev (SimulatedCrash while appending
-    KV pages): its tracked shipment never settles, so the victim is
-    reconciled dead; no decode slot is stranded."""
+    the KV entry): its tracked shipment never settles, so the victim is
+    reconciled dead; no decode slot is stranded and no device page kept."""
     ws, dec = build_llm_disagg_set(engine, name="crash", max_slots=2,
                                    segment_len=3, inline=False)
     state = {"armed": False, "fired": 0}
+    kv_entry = _entry_write(ws, "crash.decode0")
 
     def hook(client, verb, region, offset, n):
-        if (state["armed"] and verb == "write" and n > 4096
-                and region == "crash.decode0.inbox"):
+        if state["armed"] and kv_entry(verb, region, offset):
             state["armed"] = False
             state["fired"] += 1
             raise SimulatedCrash("prefill sender died mid KV writev")
@@ -253,12 +322,13 @@ def test_kv_ship_killed_by_simulated_crash_is_accounted(engine):
     assert victim in ws.dead_uids()
     assert dec.pending() == 0               # nothing stranded in a slot
     assert dec.stats["admitted"] == 1       # only the pre-crash request
+    assert ws.joins.wire_pages() == 0       # the crashed append's pages
 
 
 def test_drain_abandons_parked_decode_requests(engine):
     """Stopping the set while requests sit in decode slots tombstones
     them through fn.abandon() — parked work is dropped with accounting,
-    never silently stranded (§9)."""
+    never silently stranded (§9), and its device pages are freed."""
     ws, dec = build_llm_disagg_set(engine, name="drain", max_slots=2,
                                    segment_len=2)
     pls = [_payload(engine, i, steps=200 + i) for i in range(3)]
@@ -271,6 +341,73 @@ def test_drain_abandons_parked_decode_requests(engine):
     dead = ws.dead_uids()
     assert set(uids) <= dead
     assert dec.stats["abandoned"] >= 2
+    _assert_handoffs(ws)
+
+
+def test_parked_shipments_are_bounded(engine):
+    """Requests waiting for a slot each hold a whole cache on the device:
+    a burst well past the bound (one slot plus one prefill batch) parks
+    at most that many, holds the rest upstream as prompts, and — at the
+    default retry budget — every request still completes, none dropped."""
+    ws, dec = build_llm_disagg_set(engine, name="park", max_slots=1,
+                                   segment_len=2)
+    bound = 1 + 1
+    pls = [_payload(engine, i, steps=24, temperature=0.0) for i in range(8)]
+    with ws:
+        p = ws.proxies[0]
+        uids = [p.submit(APP_LLM_DISAGG, pl) for pl in pls]
+        held = []
+        deadline = time.monotonic() + 60
+        while dec.stats["completed"] < len(pls) and \
+                time.monotonic() < deadline:
+            held.append(dec.parked() + ws.joins.wire_pages())
+            time.sleep(0.002)
+        res = [p.wait_result(u, timeout_s=60) for u in uids]
+    for pl, r in zip(pls, res):
+        np.testing.assert_array_equal(r, _solo(engine, pl))
+    assert dec.stats["max_parked"] == bound     # reached, never passed
+    assert dec.stats["max_resident"] == 1
+    # parked caches plus those on their way: the bound, and at most the
+    # one handed over between the decode's unpack and its park
+    assert max(held) <= bound + 1
+    stats = ws.transport_stats()
+    assert stats.dropped == 0
+    assert ws.dead_uids() == set()
+    _assert_handoffs(ws)
+
+
+def test_prefill_is_not_held_behind_a_decode_segment(engine, monkeypatch):
+    """The prefill's gate reads the decoder while a tick holds its lock
+    for a whole segment: a request that arrives mid-segment is prefilled
+    at once, not after the segment."""
+    import threading
+
+    ws, dec = build_llm_disagg_set(engine, name="overlap", max_slots=2,
+                                   segment_len=2)
+    segment = dec.engine.decode_segment
+    running = threading.Event()
+
+    def slow(state, k):
+        running.set()
+        time.sleep(2.0)
+        return segment(state, k)
+
+    pls = [_payload(engine, i, steps=4, temperature=0.0) for i in range(2)]
+    with ws:
+        p = ws.proxies[0]
+        u0 = p.submit(APP_LLM_DISAGG, pls[0])
+        np.testing.assert_array_equal(p.wait_result(u0, timeout_s=60),
+                                      _solo(engine, pls[0]))  # compiled
+        monkeypatch.setattr(dec.engine, "decode_segment", slow)
+        uids = [p.submit(APP_LLM_DISAGG, pls[0])]
+        assert running.wait(timeout=30)
+        uids.append(p.submit(APP_LLM_DISAGG, pls[1]))
+        prefill = ws.instances["overlap.prefill0"]
+        assert _wait_until(lambda: prefill.stats.processed == 3,
+                           timeout_s=1.5)
+        res = [p.wait_result(u, timeout_s=60) for u in uids]
+    for pl, r in zip(pls, res):
+        np.testing.assert_array_equal(r, _solo(engine, pl))
 
 
 def test_wire_ledger_ttl_expiry_tombstones():
@@ -285,6 +422,101 @@ def test_wire_ledger_ttl_expiry_tombstones():
     assert "u1" in jt.dropped_uids
     assert jt.stats.expired_shipments == 1
     assert jt.wire_pending() == 0
+
+
+def test_wire_ledger_holds_a_device_handoff_until_settled_or_dead():
+    """The wire ledger's ends of a handoff: the receiver's settle takes
+    its pages, a tombstone drops them at once, an expiry at the TTL sweep,
+    and a stopping set's release drops what is left unsettled."""
+    import jax.numpy as jnp
+
+    t = {"now": 0.0}
+    jt = JoinTable(None, ttl_s=5.0, clock=lambda: t["now"])
+    pages = {u: [jnp.full(4, i)] for i, u in
+             enumerate(("ok", "dead", "lost", "stopped"))}
+    for uid in ("ok", "dead", "lost"):
+        jt.track_wire(uid, pages[uid])
+    assert jt.wire_pages() == 3
+    assert jt.settle_wire("ok") is pages["ok"]
+    assert jt.settle_wire("ok") is None          # taken once
+    jt.mark_dropped("dead")
+    jt.track_wire("dead", pages["dead"])          # a dead uid tracks nothing
+    assert jt.wire_pages() == 1
+    t["now"] = 10.0
+    jt.mark_dropped("other")  # runs the sweep
+    assert "lost" in jt.dropped_uids and jt.wire_pages() == 0
+    assert jt.settle_wire("lost") is None
+    jt.track_wire("stopped", pages["stopped"])
+    jt.release_wire_pages()
+    assert jt.wire_pages() == 0 and jt.wire_pending() == 1
+
+
+def test_device_handoff_dropped_on_a_full_ring_frees_its_pages(engine):
+    """An append that finds the decode ring full through its retries drops
+    the handoff (§9): the uid is tombstoned and the ledger lets go of its
+    pages."""
+    import jax.numpy as jnp
+
+    from repro.core.ring_buffer import RingProducer
+
+    ws, _ = build_llm_disagg_set(engine, name="full", max_slots=1)
+    # the set is not started, so nothing reads the decode inbox: fill it
+    filler = RingProducer(ws.instances["full.decode0"].inbox, 1)
+    while filler.append(b"x"):
+        pass
+    msg = WorkflowMessage.new(APP_LLM_DISAGG, payload=KVPages(
+        meta={"start": 1}, pages=[jnp.ones(3), jnp.zeros((1, 2))]))
+    rd = ws.instances["full.prefill0"].rd
+    assert rd.deliver_many([msg], "prefill") == 0
+    assert msg.uid_hex in ws.joins.dropped_uids
+    assert ws.joins.wire_pages() == 0
+    stats = ws.transport_stats()
+    assert stats.dropped == 1 and stats.kv_device_handoffs == 0
+
+
+def test_reassignment_hands_a_device_handoff_to_a_peer(engine):
+    """A decode instance reassigned with a handoff still in its inbox
+    passes it to a peer decode instance: the pages go back into the wire
+    ledger with the re-sent entry, and the peer takes the same arrays."""
+    import jax.numpy as jnp
+
+    from repro.core.batching import Coalescer
+
+    ws, _ = build_llm_disagg_set(engine, name="move", max_slots=1,
+                                 n_decode=2)
+    pages = [jnp.ones(3), jnp.zeros((1, 2))]
+    msg = WorkflowMessage.new(APP_LLM_DISAGG, payload=KVPages(
+        meta={"start": 1}, pages=pages))
+    uid = msg.uid_hex
+    assert ws.instances["move.prefill0"].rd.deliver_many([msg], "prefill")
+    src, dst = (ws.instances[f"move.decode{i}"] for i in range(2))
+    if dst.inbox.ready():                     # round robin picked decode1
+        src, dst = dst, src
+    ws.nm.assign(src.name, "prefill")
+    src._poll_assignment()
+    src._apply_reassignment(Coalescer(max_batch=1, max_wait_s=0.01))
+    assert src.stats.handoffs == 1
+    assert ws.joins.wire_pages() == 1         # travelling again
+    got = dst._unpack(dst.inbox.poll())
+    assert got.uid_hex == uid
+    assert all(a is b for a, b in zip(got.payload.pages, pages))
+    assert ws.joins.wire_pending() == 0
+
+
+def test_device_kv_entry_carries_the_meta_alone():
+    """A device handoff's ring entry is its meta: it decodes with no
+    pages, for the receiver to take from the wire ledger."""
+    import jax.numpy as jnp
+
+    pages = [jnp.arange(4.0), jnp.ones((2, 1, 3))]
+    meta = {"start": 4, "steps": 2, "seed": 0, "temperature": 0.0,
+            "prompt": [1, 2, 3, 4]}
+    kv = KVPages(meta=meta, pages=pages)
+    assert kv.on_device and kv.nbytes == 4 * 4 + 6 * 4
+    wire = WorkflowMessage.new(app_id=1, payload=kv).pack()
+    assert len(wire) < 256                    # no page bytes on the ring
+    out = WorkflowMessage.unpack(wire).payload
+    assert out.meta == meta and out.pages is None
 
 
 def test_kv_pages_roundtrip_zero_copy():
@@ -305,37 +537,24 @@ def test_kv_pages_roundtrip_zero_copy():
 
 
 # ================================================ continuous batching
-def test_continuous_batching_random_join_leave_property(engine):
+@pytest.mark.parametrize("family", ["qwen3", "rwkv6"])
+def test_continuous_batching_random_join_leave_property(engines, family):
     """Property: any random join/leave schedule over the slot batch
     produces, per request, exactly the solo run's tokens.  Requests with
-    different lengths/seeds/temperatures enter whenever a slot frees."""
+    different lengths/seeds/temperatures enter whenever a slot frees,
+    their caches handed over on the device."""
+    engine = engines(family)
     rng = random.Random(0)
     dec = ContinuousDecoder(engine, max_slots=3, segment_len=2)
+    prefill = make_prefill_fn(engine)
     reqs = []
     for i in range(8):
         pl = _payload(engine, i, steps=rng.randint(3, 12),
                       temperature=rng.choice([0.0, 0.7, 1.3]))
         reqs.append(pl)
     expected = {f"u{i}": _solo(engine, pl) for i, pl in enumerate(reqs)}
-
-    logits_cache = {}
-    for i, pl in enumerate(reqs):
-        logits, cache = engine.prefill(pl["prompt"])
-        logits_cache[f"u{i}"] = (np.asarray(logits), cache)
-
-    import jax
-
-    def ship(uid, pl):
-        logits, cache = logits_cache[uid]
-        leaves = jax.tree_util.tree_leaves(cache)
-        axes = jax.tree_util.tree_leaves(engine.batch_axes)
-        pages = [logits[0]] + [np.take(np.asarray(leaf), [0], axis=int(ax))
-                               for leaf, ax in zip(leaves, axes)]
-        return KVPages(meta={"prompt": pl["prompt"][0].tolist(),
-                             "start": pl["prompt"].shape[1],
-                             "steps": pl["steps"],
-                             "temperature": pl["temperature"],
-                             "seed": pl["seed"]}, pages=pages)
+    ships = {f"u{i}": prefill(pl) for i, pl in enumerate(reqs)}
+    assert all(kv.on_device for kv in ships.values())
 
     pending = list(enumerate(reqs))
     rng.shuffle(pending)
@@ -345,7 +564,7 @@ def test_continuous_batching_random_join_leave_property(engine):
         for _ in range(rng.randint(0, 2)):
             if pending:
                 i, pl = pending.pop()
-                dec(ship(f"u{i}", pl), uid=f"u{i}")
+                dec(ships.pop(f"u{i}"), uid=f"u{i}")
         for uid, toks in dec.tick():
             got[uid] = toks
         if not pending and dec.pending() == 0 and len(got) < len(reqs):

@@ -22,7 +22,7 @@ SPANS = {
     "onepiece.sched.wait": {"instance"},
     "onepiece.recv": {"instance", "uid", "bytes"},
     "onepiece.stage": {"instance", "stage", "uids"},
-    "onepiece.handoff.pull": {"uids", "bytes"},
+    "onepiece.handoff.pull": {"uids", "bytes", "placement"},
     "onepiece.deliver": {"instance", "stage", "uids", "bytes"},
     "onepiece.decode.tick": {"seq"},
     "onepiece.decode.insert": {"uid", "slot", "start", "bytes"},
