@@ -24,6 +24,7 @@ _ARCH_MODULES = {
     "gemma3-27b": "repro.configs.gemma3_27b",
     "deepseek-moe-16b": "repro.configs.deepseek_moe_16b",
     "whisper-large-v3": "repro.configs.whisper_large_v3",
+    "granite-4.0-h-small": "repro.configs.granite_4h_small",
 }
 
 ARCH_IDS = tuple(_ARCH_MODULES)
@@ -33,6 +34,15 @@ def get_config(arch_id: str) -> ModelConfig:
     if arch_id not in _ARCH_MODULES:
         raise KeyError(f"unknown arch {arch_id!r}; available: {sorted(_ARCH_MODULES)}")
     return importlib.import_module(_ARCH_MODULES[arch_id]).CONFIG
+
+
+def get_chip_config(arch_id: str) -> ModelConfig:
+    """The share of ``arch_id`` one chip holds in its stated deployment
+    (the module's ``ONE_CHIP``), or the whole config where one chip holds
+    all of it."""
+    get_config(arch_id)
+    mod = importlib.import_module(_ARCH_MODULES[arch_id])
+    return getattr(mod, "ONE_CHIP", mod.CONFIG)
 
 
 def get_shape(shape_id: str) -> ShapeConfig:
@@ -54,6 +64,7 @@ __all__ = [
     "ShapeConfig",
     "V5E",
     "all_configs",
+    "get_chip_config",
     "get_config",
     "get_shape",
     "supported_shapes",

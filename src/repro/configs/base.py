@@ -21,7 +21,8 @@ class ModelConfig:
 
     One instance per assigned architecture lives in ``repro/configs/<id>.py``.
     ``family`` selects the model implementation:
-      dense | moe | ssm (rwkv6) | hybrid (zamba2) | vlm | audio (enc-dec)
+      dense | moe | ssm (rwkv6) | hybrid (zamba2, granite-4.0-h) | vlm |
+      audio (enc-dec)
     """
 
     name: str
@@ -36,7 +37,9 @@ class ModelConfig:
 
     # --- attention features -------------------------------------------------
     qk_norm: bool = False
+    rope: bool = True                # False: no positional encoding (NoPE)
     rope_theta: float = 10_000.0
+    attention_multiplier: float = 0.0  # score scale; 0 -> 1/sqrt(head_dim)
     rope_2d: bool = False            # chatglm-style 2d rope (half-dim rotary)
     sliding_window: int = 0          # >0: window size for "local" layers
     local_global_pattern: Tuple[int, int] = (0, 0)  # (n_local, n_global) period
@@ -49,12 +52,17 @@ class ModelConfig:
     first_dense_layers: int = 0      # leading dense layers (deepseek-moe)
     dense_ff: int = 0                # d_ff of those dense layers (0 -> d_ff)
     capacity_factor: float = 1.0
+    router_experts: int = 0          # experts the router spans; 0 -> num_experts
+    expert_offset: int = 0           # first of the num_experts held here
+    shared_ff: int = 0               # shared-MLP width (0 -> num_shared_experts * d_ff)
 
     # --- SSM / hybrid -------------------------------------------------------
     ssm_state: int = 0
     ssm_head_dim: int = 64
     ssm_expand: int = 2
     hybrid_attn_every: int = 0       # shared attn block every N ssm layers
+    attn_layer_period: int = 0       # >0: layer pattern of this period, all
+    attn_layer_offset: int = 0       # Mamba2 but attention at this offset
 
     # --- encoder-decoder / frontend stubs ------------------------------------
     encoder_layers: int = 0
@@ -73,6 +81,9 @@ class ModelConfig:
                                      # instead of all-reducing activations (§Perf)
     vocab_round: int = 256
     tie_embeddings: bool = False
+    embedding_multiplier: float = 1.0  # muP scales (granite)
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0      # logits are divided by this
     source: str = ""                 # citation from the assignment pool
 
     # ------------------------------------------------------------------ utils
@@ -91,6 +102,14 @@ class ModelConfig:
     @property
     def resolved_cache_dtype(self) -> str:
         return self.cache_dtype or self.dtype
+
+    @property
+    def resolved_router_experts(self) -> int:
+        return self.router_experts or self.num_experts
+
+    @property
+    def resolved_shared_ff(self) -> int:
+        return self.shared_ff or self.num_shared_experts * self.d_ff
 
     @property
     def d_inner(self) -> int:
@@ -125,6 +144,10 @@ class ModelConfig:
             ratio = max(1, self.num_heads // max(1, self.resolved_kv_heads))
             num_kv = max(1, num_heads // ratio)
         num_experts = min(self.num_experts, 4) if self.num_experts else 0
+        # an expert share keeps routing over more experts than it holds
+        router = min(self.router_experts, 2 * num_experts) \
+            if self.router_experts > self.num_experts else 0
+        period = 2 if self.attn_layer_period else 0
         return replace(
             self,
             num_layers=2,
@@ -136,6 +159,10 @@ class ModelConfig:
             dense_ff=min(self.dense_ff, 512) if self.dense_ff else 0,
             vocab_size=min(self.vocab_size, 1024),
             num_experts=num_experts,
+            router_experts=router,
+            shared_ff=min(self.shared_ff, 512) if self.shared_ff else 0,
+            attn_layer_period=period,
+            attn_layer_offset=min(self.attn_layer_offset, 1) if period else 0,
             num_shared_experts=min(self.num_shared_experts, 1),
             top_k=min(self.top_k, 2) if self.top_k else 0,
             first_dense_layers=min(self.first_dense_layers, 1),

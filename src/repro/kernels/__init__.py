@@ -8,8 +8,8 @@ Each kernel package has:
 The paper itself has no kernel-level contribution (it is a serving system);
 these cover the stages it schedules: prefill attention (LM + DiT), long-KV
 decode attention (full-precision and int8-quantized cache), the fused DDIM
-sampling step, and the RWKV6 recurrence for the attention-free assigned
-arch.  The model-side entry points in ``repro.models.layers`` route here
+sampling step, the RWKV6 recurrence for the attention-free assigned
+arch, and the Mamba2 SSD recurrence for the hybrid ones.  The model-side entry points in ``repro.models.layers`` route here
 through the ``use_pallas`` dispatch layer (docs/kernels.md).
 """
 from __future__ import annotations
@@ -45,6 +45,7 @@ from repro.kernels.decode_attention.ops import (  # noqa: E402
     quantize_kv,
 )
 from repro.kernels.rwkv6_wkv.ops import wkv6  # noqa: E402
+from repro.kernels.ssd_scan.ops import ssd_scan  # noqa: E402
 from repro.kernels.ddim_step.ops import ddim_step  # noqa: E402
 
 __all__ = [
@@ -56,5 +57,6 @@ __all__ = [
     "decode_attention_int8_cache",
     "quantize_kv",
     "wkv6",
+    "ssd_scan",
     "ddim_step",
 ]

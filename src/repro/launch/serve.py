@@ -25,7 +25,8 @@ Workflows (docs/workflows.md):
             KVPages (kept on the device: both stages run one engine) to a
             continuous-batching decode stage.  ``--llm-size reduced`` (float32, CPU-sized) checks the
             tokens bit-identical to solo generate; ``published`` (the
-            config's own widths and dtype) holds them to solo generate
+            config's own widths and dtype, one chip's share of a model
+            that needs more) holds them to solo generate
             within the bf16 tolerance of ``check_llm_tokens``.
 
 Any stage error, drop, dead request, solo fallback, timeout or control-loop
@@ -275,14 +276,15 @@ def report(failures: List[str]) -> int:
 
 # --------------------------------------------------------------------- llm
 def llm_config(arch: str, size: str):
-    """``published``: the config's own widths and dtype; ``reduced``: the
-    CPU-sized float32 preset of the tests."""
-    from repro.configs import get_config
+    """``published``: the config's own widths and dtype, as much of it as
+    one chip holds in its stated deployment (``get_chip_config``: fewer
+    layers or experts where the whole model does not fit); ``reduced``:
+    the CPU-sized float32 preset of the tests."""
+    from repro.configs import get_chip_config, get_config
 
-    cfg = get_config(arch)
     if size == "reduced":
-        cfg = dataclasses.replace(cfg.reduced(), dtype="float32")
-    return cfg
+        return dataclasses.replace(get_config(arch).reduced(), dtype="float32")
+    return get_chip_config(arch)
 
 
 def build_llm(cfg, *, max_len: int, slots: int, segment: int,
@@ -400,6 +402,24 @@ def device_share(stats) -> str:
     return f"{stats.kv_device_handoffs}/{stats.kv_pages}"
 
 
+def handoff_lines(engine, decoder) -> List[str]:
+    """What one handoff carries, recurrent state against KV, and for a
+    model with experts the routed rows its held experts took."""
+    split = engine.handoff_split
+    lines = [f"handoff per request: state_bytes {split['state_bytes']} "
+             f"({split['state_bytes']/1e6:.2f} MB) against kv_bytes "
+             f"{split['kv_bytes']} ({split['kv_bytes']/1e6:.2f} MB)"]
+    if engine.moe_layers:
+        st = decoder.stats
+        lines.append(
+            f"experts: expert_rows={st['expert_rows']} "
+            f"expert_rows_absent={st['expert_rows_absent']}, "
+            f"{decoder.expert_rows_per_step():.3f} rows per held expert per "
+            f"decode step ({engine.cfg.num_experts} held of "
+            f"{engine.cfg.resolved_router_experts}, {engine.moe_layers} layers)")
+    return lines
+
+
 def run_llm(args) -> int:
     """--workflow llm: the two-stage llm_disagg DAG end-to-end.
 
@@ -439,6 +459,8 @@ def run_llm(args) -> int:
     print(f"kv shipping: {stats.kv_pages} KVPages messages, "
           f"{stats.kv_bytes/1e6:.1f} MB of cache, "
           f"{device_share(stats)} of handoffs kept on the device")
+    for line in handoff_lines(engine, decoder):
+        print(line)
     if args.profile_latency:
         print_latency()
     return report(fails)
@@ -514,12 +536,13 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
                     help="stage topology: linear chain, the branch-parallel "
                          "Wan DAG, the nested audio-to-video DAG, or the "
                          "disaggregated prefill/decode LLM split")
-    ap.add_argument("--llm-arch", default="qwen3-1.7b",
+    ap.add_argument("--llm-arch", "--arch", default="qwen3-1.7b",
                     help="--workflow llm: model config")
     ap.add_argument("--llm-size", default="reduced",
                     choices=["reduced", "published"],
                     help="--workflow llm: the CPU-sized float32 preset, or "
-                         "the config's published widths in its own dtype")
+                         "the config's published widths in its own dtype "
+                         "(one chip's share of a model that needs more)")
     ap.add_argument("--llm-max-len", type=int, default=64,
                     help="--workflow llm: cache positions per request")
     ap.add_argument("--llm-prompt-len", type=int, default=4,

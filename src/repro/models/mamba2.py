@@ -1,15 +1,26 @@
-"""Mamba2 (SSD) blocks and the Zamba2 hybrid (arXiv:2411.15242).
+"""Mamba2 (SSD) blocks and the two hybrid stacks built from them.
 
 Mamba2 block: in-proj -> (z, xBC, dt); depthwise causal conv over xBC;
 selective state-space recurrence
     S_t = exp(dt_t * A) S_{t-1} + (dt_t x_t) B_t^T ,   y_t = S_t C_t + D x_t
-with per-head scalar A; gated RMSNorm; out-proj.
+with per-head scalar A; gated RMSNorm; out-proj.  The prefill recurrence
+runs the ``repro.kernels.ssd_scan`` Pallas kernel where the dispatch layer
+says so (``models/layers.py``); decode is the single-step update.
 
-Zamba2: a stack of Mamba2 layers with ONE shared transformer block
-(attention + SwiGLU, weights reused) applied after every
+Zamba2 (arXiv:2411.15242): a stack of Mamba2 layers with ONE shared
+transformer block (attention + SwiGLU, weights reused) applied after every
 ``hybrid_attn_every`` SSM layers — scan over periods with the shared block
 closed over.  Decode state: per-layer (conv_state [B,conv_dim,3],
 ssd_state [B,H,hd,d_state]) + a KV cache per shared-block application.
+
+Layer pattern (granite-4.0-h, ``attn_layer_period`` > 0): periods of
+``attn_layer_period`` layers, each a Mamba2 mixer but the attention mixer
+(``transformer._attention``) at ``attn_layer_offset``; after every mixer
+the MoE of ``models/moe.py`` plus its shared MLP.  Both residual branches
+are scaled by ``residual_multiplier``, embeddings by
+``embedding_multiplier``, logits divided by ``logits_scaling``.  Scanned
+over periods as ``transformer.py`` scans gemma3's.  Decode state: the
+Mamba2 states of every Mamba2 layer and a KV cache per attention layer.
 """
 from __future__ import annotations
 
@@ -53,14 +64,25 @@ def mamba_param_specs(cfg: ModelConfig, nl: int) -> Tree:
 
 
 # ----------------------------------------------------------------- ssd core
-def ssd_scan(x, dt, a, B, C, state, chunk: int = 256):
-    """x: [B,T,H,P]; dt/a: [B,T,H]; B/C: [B,T,N]; state: [B,H,P,N].
-    Returns (y [B,T,H,P], final state).  Chunked + checkpointed so the
-    backward saves state per chunk, not per step (cf. rwkv6.wkv6_scan)."""
+def ssd_scan(x, dt, la, B, C, state, chunk: int = 256, use_pallas=None):
+    """x: [B,T,H,P]; dt/la: [B,T,H] (step size, log decay); B/C: [B,T,N];
+    state: [B,H,P,N].  Returns (y [B,T,H,P], final state).
+
+    ``use_pallas`` routes to the ``repro.kernels.ssd_scan`` Pallas kernel
+    (forward-only — the train path forces this scan, whose checkpointed
+    chunks the backward needs); the scan below is its oracle.  Chunked +
+    checkpointed so the backward saves state per chunk, not per step (cf.
+    rwkv6.wkv6_scan)."""
+    if L.resolve_use_pallas(use_pallas):
+        from repro.kernels.ssd_scan.ops import ssd_scan as ssd_kernel
+
+        L._record("ssd_scan", "pallas")
+        return ssd_kernel(x, dt, la, B, C, state.astype(jnp.float32))
+    L._record("ssd_scan", "reference")
 
     def step(s, xs):
-        xt, dtt, at, bt, ct = xs
-        s = at[..., None, None] * s + jnp.einsum(
+        xt, dtt, lat, bt, ct = xs
+        s = jnp.exp(lat)[..., None, None] * s + jnp.einsum(
             "bhp,bn->bhpn", xt * dtt[..., None], bt
         )
         y = jnp.einsum("bhpn,bn->bhp", s, ct)
@@ -73,7 +95,7 @@ def ssd_scan(x, dt, a, B, C, state, chunk: int = 256):
     nc = t // chunk
     xs = jax.tree.map(
         lambda v: v.reshape(v.shape[0], nc, chunk, *v.shape[2:]).swapaxes(0, 1),
-        (x, dt, a, B, C),
+        (x, dt, la, B, C),
     )
 
     @jax.checkpoint
@@ -87,12 +109,14 @@ def ssd_scan(x, dt, a, B, C, state, chunk: int = 256):
     return ys, state
 
 
-def ssd_step(x, dt, a, B, C, state):
-    """Single token: x [B,H,P], dt/a [B,H], B/C [B,N]."""
-    state = a[..., None, None] * state + jnp.einsum(
-        "bhp,bn->bhpn", x * dt[..., None], B
+def ssd_step(x, dt, la, B, C, state):
+    """Single token: x [B,H,P], dt/la [B,H], B/C [B,N].  The float32 state
+    is read at float32 precision, as the prefill kernel reads it."""
+    hi = jax.lax.Precision.HIGHEST
+    state = jnp.exp(la)[..., None, None] * state + jnp.einsum(
+        "bhp,bn->bhpn", x * dt[..., None], B, precision=hi
     )
-    y = jnp.einsum("bhpn,bn->bhp", state, C)
+    y = jnp.einsum("bhpn,bn->bhp", state, C, precision=hi)
     return y, state
 
 
@@ -108,8 +132,10 @@ def _gated_norm(y, z, w, eps):
     return L.rms_norm(y * jax.nn.silu(z), w, eps)
 
 
-def mamba_layer(x, lp, cfg: ModelConfig, cache, seq_mode: bool):
-    """cache: (conv_state [B,conv_dim,W-1], ssd_state [B,H,P,N])."""
+def mamba_layer(x, lp, cfg: ModelConfig, cache, seq_mode: bool,
+                use_pallas=None):
+    """cache: (conv_state [B,conv_dim,W-1], ssd_state [B,H,P,N]).
+    Returns (residual delta, new cache)."""
     bsz, t, d = x.shape
     d_inner, n_heads, conv_dim, _ = _dims(cfg)
     hd, ns = cfg.ssm_head_dim, cfg.ssm_state
@@ -136,20 +162,20 @@ def mamba_layer(x, lp, cfg: ModelConfig, cache, seq_mode: bool):
     Bm = xBC_conv[..., d_inner : d_inner + ns]
     Cm = xBC_conv[..., d_inner + ns :]
     dtv = jax.nn.softplus(dt_raw.astype(jnp.float32) + lp["dt_bias"])
-    a = jnp.exp(-jnp.exp(lp["a_log"]) * dtv)  # [B,T,H]
+    la = -jnp.exp(lp["a_log"]) * dtv  # [B,T,H] log decay
     xs32 = xs.astype(jnp.float32)
     if seq_mode:
-        y, new_ssd = ssd_scan(xs32, dtv, a, Bm.astype(jnp.float32),
-                              Cm.astype(jnp.float32), ssd_state)
+        y, new_ssd = ssd_scan(xs32, dtv, la, Bm.astype(jnp.float32),
+                              Cm.astype(jnp.float32), ssd_state,
+                              use_pallas=use_pallas)
     else:
-        y, new_ssd = ssd_step(xs32[:, 0], dtv[:, 0], a[:, 0],
+        y, new_ssd = ssd_step(xs32[:, 0], dtv[:, 0], la[:, 0],
                               Bm.astype(jnp.float32)[:, 0],
                               Cm.astype(jnp.float32)[:, 0], ssd_state)
         y = y[:, None]
     y = y + lp["d_skip"][None, None, :, None] * xs32
     y = y.reshape(bsz, t, d_inner).astype(x.dtype)
     out = _gated_norm(y, z, lp["gn_w"], cfg.norm_eps) @ lp["w_out"]
-    out = constrain(x + out, "batch", "seq_res", "act_embed")
     return out, (new_conv, new_ssd)
 
 
@@ -168,15 +194,7 @@ def shared_block_specs(cfg: ModelConfig) -> Tree:
 def _shared_block(x, sp, cfg: ModelConfig, mode, cache, cur_index):
     from repro.models.transformer import _attention, _sincos
 
-    s = x.shape[1]
-    if mode == "decode":
-        if jnp.ndim(cur_index) > 0:  # per-row positions [B] -> [B,1]
-            positions = jnp.asarray(cur_index, jnp.int32)[:, None]
-        else:
-            positions = jnp.full((x.shape[0], 1), cur_index, jnp.int32)
-    else:
-        positions = jnp.arange(s)[None, :].repeat(x.shape[0], 0)
-    sincos = _sincos(cfg, positions)
+    sincos = _sincos(cfg, _positions(x, mode, cur_index))
     delta, new_cache = _attention(x, sp, cfg, mode, sincos, 0, cache, cur_index)
     x = x + delta
     h = L.rms_norm(x, sp["mlp_norm"], cfg.norm_eps)
@@ -185,7 +203,7 @@ def _shared_block(x, sp, cfg: ModelConfig, mode, cache, cur_index):
 
 
 # ------------------------------------------------------------------ zamba2
-def abstract_params(cfg: ModelConfig) -> Tree:
+def _zamba_params(cfg: ModelConfig) -> Tree:
     dt = cfg.dtype
     p: Tree = {
         "embedding": ParamSpec((cfg.vocab_padded, cfg.d_model), ("vocab", "embed"), dt, "small"),
@@ -215,6 +233,7 @@ def _zero_mamba_cache(cfg: ModelConfig, batch: int, nl: int):
 def _stack(params, x, cfg: ModelConfig, mode, cache, cur_index, remat):
     n_p, every, tail = _periods(cfg)
     seq_mode = mode != "decode"
+    up = "off" if mode == "train" else cfg.use_pallas   # kernels: forward only
     n_main = n_p * every
     shared = params.get("shared")
 
@@ -232,8 +251,8 @@ def _stack(params, x, cfg: ModelConfig, mode, cache, cur_index, remat):
 
         def inner(c2, xs2):
             lp, mc = xs2
-            y, nmc = mamba_layer(c2, lp, cfg, mc, seq_mode)
-            return y, nmc
+            delta, nmc = mamba_layer(c2, lp, cfg, mc, seq_mode, up)
+            return constrain(c2 + delta, "batch", "seq_res", "act_embed"), nmc
 
         xx, nmc = jax.lax.scan(inner, xx, (lp_p, mc_p))
         nac = None
@@ -254,7 +273,8 @@ def _stack(params, x, cfg: ModelConfig, mode, cache, cur_index, remat):
     for i in range(tail):
         lp = jax.tree.map(lambda a: a[i], tail_p)
         mc = jax.tree.map(lambda a: a[i], tail_c)
-        x, nmc = mamba_layer(x, lp, cfg, mc, seq_mode)
+        delta, nmc = mamba_layer(x, lp, cfg, mc, seq_mode, up)
+        x = constrain(x + delta, "batch", "seq_res", "act_embed")
         ntail.append(nmc)
 
     if mode != "train":
@@ -272,7 +292,7 @@ def _stack(params, x, cfg: ModelConfig, mode, cache, cur_index, remat):
     return x, (new_cache or None)
 
 
-def loss_fn(params: Tree, batch: Tree, cfg: ModelConfig, **_):
+def _zamba_loss(params: Tree, batch: Tree, cfg: ModelConfig, **_):
     x = jnp.take(params["embedding"], batch["tokens"], axis=0)
     x = constrain(x, "batch", "seq_res", "act_embed")
     x, _ = _stack(params, x, cfg, "train", None, None, remat=True)
@@ -281,24 +301,239 @@ def loss_fn(params: Tree, batch: Tree, cfg: ModelConfig, **_):
     return ce, {"ce": ce, "aux": 0.0}
 
 
-def prefill(params: Tree, batch: Tree, cfg: ModelConfig, **_):
+def _zamba_prefill(params: Tree, batch: Tree, cfg: ModelConfig, **_):
     x = jnp.take(params["embedding"], batch["tokens"], axis=0)
     x, cache = _stack(params, x, cfg, "prefill", None, None, remat=False)
     x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
     return (x[:, -1] @ params["unembed"]).astype(jnp.float32), cache
 
 
-def decode_step(params: Tree, cache: Tree, batch: Tree, cfg: ModelConfig, **_):
+def _zamba_decode(params: Tree, cache: Tree, batch: Tree, cfg: ModelConfig, **_):
     x = jnp.take(params["embedding"], batch["tokens"][:, None], axis=0)
     x, ncache = _stack(params, x, cfg, "decode", cache, batch["cur_index"], remat=False)
     x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
     return (x[:, 0] @ params["unembed"]).astype(jnp.float32), ncache
 
 
+# ------------------------------------------------ layer pattern (granite-4.0-h)
+def hybrid_pattern(cfg: ModelConfig) -> Tuple[int, int, int]:
+    """(n_periods, period, attention offset) of a layer-pattern stack."""
+    period = cfg.attn_layer_period
+    if cfg.num_layers % period:
+        raise ValueError(f"{cfg.name}: {cfg.num_layers} layers are not whole "
+                         f"periods of {period}")
+    return cfg.num_layers // period, period, cfg.attn_layer_offset
+
+
+EXPERTS = ("we_gate", "we_up", "we_down")
+
+
+def _pattern_params(cfg: ModelConfig) -> Tree:
+    from repro.models.moe import moe_param_specs
+    from repro.models.transformer import _attn_specs
+
+    n_p, period, _ = hybrid_pattern(cfg)
+    dt, v, d = cfg.dtype, cfg.vocab_padded, cfg.d_model
+    p: Tree = {
+        "embedding": ParamSpec((v, d), ("vocab", "embed"), dt, "small"),
+        "final_norm": ParamSpec((d,), ("embed",), dt, "zeros"),
+        "mamba": mamba_param_specs(cfg, n_p * (period - 1)),
+        "attn": _attn_specs(cfg, n_p, dt),
+        "moe": moe_param_specs(cfg, cfg.num_layers, dt),
+    }
+    if not cfg.tie_embeddings:
+        p["unembed"] = ParamSpec((d, v), ("embed", "vocab"), dt, "small")
+    return p
+
+
+def _positions(x, mode, cur_index):
+    if mode == "decode":
+        if jnp.ndim(cur_index) > 0:  # per-row positions [B] -> [B,1]
+            return jnp.asarray(cur_index, jnp.int32)[:, None]
+        return jnp.full((x.shape[0], 1), cur_index, jnp.int32)
+    return jnp.arange(x.shape[1])[None, :].repeat(x.shape[0], 0)
+
+
+def _take(tree, i):
+    return jax.tree.map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False), tree)
+
+
+def _put(tree, new, i):
+    return jax.tree.map(
+        lambda a, n: jax.lax.dynamic_update_index_in_dim(
+            a, n.astype(a.dtype), i, 0), tree, new)
+
+
+def _pattern_stack(params, x, cfg: ModelConfig, mode, cache, cur_index,
+                   remat, dropless):
+    """-> (hidden, new cache, aux): aux is the routers' balance loss, or on
+    the served MoE path each token's held-expert rows over all layers.
+
+    The caches of every layer ride through the layer loops as one carried
+    buffer per leaf, each layer reading and writing its own row, so a
+    decode step updates the slot state in place rather than stacking a
+    fresh copy of it."""
+    from repro.models.transformer import _attention, _ffn, _sincos, served_moe
+
+    n_p, period, off = hybrid_pattern(cfg)
+    nm = period - 1
+    seq_mode = mode != "decode"
+    up = "off" if mode == "train" else cfg.use_pallas   # kernels: forward only
+    served = served_moe(mode, dropless)
+    rm = jnp.asarray(cfg.residual_multiplier, x.dtype)
+    sincos = _sincos(cfg, _positions(x, mode, cur_index))
+    b, t = x.shape[:2]
+
+    def periods(a, n):
+        return a.reshape((n_p, n) + a.shape[1:])
+
+    mc = cache["mamba"] if cache else _zero_mamba_cache(cfg, b, n_p * nm)
+    if cache:
+        kv = cache["attn"]
+    else:   # prefill: each attention layer's keys and values of the prompt
+        shape = (n_p, b, cfg.resolved_kv_heads, t, cfg.resolved_head_dim)
+        cd = jnp.dtype(cfg.resolved_cache_dtype)
+        kv = (jnp.zeros(shape, cd), jnp.zeros(shape, cd))
+    mp = jax.tree.map(lambda a: periods(a, nm), params["mamba"])
+    experts = {k: params["moe"][k] for k in EXPERTS}
+    moe_p = jax.tree.map(lambda a: periods(a, period),
+                         {k: a for k, a in params["moe"].items()
+                          if k not in EXPERTS})
+
+    def moe_block(xx, lp, layer):
+        if served:   # the loop reads this layer's experts in place
+            return _ffn(xx, dict(lp, **experts), cfg, True, dropless, mode,
+                        layer=layer)
+        return _ffn(xx, dict(lp, **{k: w[layer] for k, w in experts.items()}),
+                    cfg, True, dropless, mode)
+
+    def residual(xx, aux, delta, moe_lp, layer):
+        xx = xx + rm * delta
+        ff, a = moe_block(xx, moe_lp, layer)
+        return constrain(xx + rm * ff, "batch", "seq_res", "act_embed"), aux + a
+
+    def mamba_run(carry, lps, moe_lps, first, layers):
+        """Mamba2 layers ``first ..`` of the stack (global layer indices
+        ``layers``), each followed by its MoE."""
+        if layers.shape[0] == 0:
+            return carry
+
+        def body(carry, xs):
+            xx, aux, mc = carry
+            lp, moe_lp, j, layer = xs
+            delta, nc = mamba_layer(xx, lp, cfg, _take(mc, j), seq_mode, up)
+            xx, aux = residual(xx, aux, delta, moe_lp, layer)
+            return (xx, aux, _put(mc, nc, j)), None
+
+        js = first + jnp.arange(layers.shape[0])
+        carry, _ = jax.lax.scan(body, carry, (lps, moe_lps, js, layers))
+        return carry
+
+    def period_body(carry, xs):
+        xx, aux, mc, kv = carry
+        i, lp_m, lp_a, lp_moe = xs
+        layers = i * period + jnp.arange(period)
+
+        def part(tree, lo, hi):
+            return jax.tree.map(lambda a: a[lo:hi], tree)
+
+        xx, aux, mc = mamba_run((xx, aux, mc), part(lp_m, 0, off),
+                                part(lp_moe, 0, off), i * nm, layers[:off])
+        delta, nkv = _attention(xx, lp_a, cfg, mode, sincos, 0,
+                                _take(kv, i) if cache else None, cur_index)
+        xx, aux = residual(xx, aux, delta,
+                           jax.tree.map(lambda a: a[off], lp_moe),
+                           layers[off])
+        if nkv is not None:   # train keeps no cache
+            kv = _put(kv, nkv, i)
+        xx, aux, mc = mamba_run((xx, aux, mc), part(lp_m, off, nm),
+                                part(lp_moe, off + 1, period), i * nm + off,
+                                layers[off + 1:])
+        return (xx, aux, mc, kv), None
+
+    if remat:
+        period_body = jax.checkpoint(
+            period_body, policy=jax.checkpoint_policies.nothing_saveable)
+    aux0 = jnp.zeros(x.shape[:2] if served else (), jnp.float32)
+    (x, aux, mc, kv), _ = jax.lax.scan(
+        period_body, (x, aux0, mc, kv),
+        (jnp.arange(n_p), mp, params["attn"], moe_p))
+    new_cache = None if mode == "train" else {"mamba": mc, "attn": kv}
+    return x, new_cache, aux
+
+
+def _pattern_embed(params, tokens, cfg: ModelConfig):
+    x = jnp.take(params["embedding"], tokens, axis=0)
+    x = x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
+    return constrain(x, "batch", "seq_res", "act_embed")
+
+
+def _unembed(params, cfg: ModelConfig):
+    return params["embedding"].T if cfg.tie_embeddings else params["unembed"]
+
+
+def _pattern_logits(params, x, cfg: ModelConfig):
+    return (x @ _unembed(params, cfg)).astype(jnp.float32) / cfg.logits_scaling
+
+
+# ---------------------------------------------------------------- public API
+def abstract_params(cfg: ModelConfig) -> Tree:
+    if cfg.attn_layer_period:
+        return _pattern_params(cfg)
+    return _zamba_params(cfg)
+
+
+def loss_fn(params: Tree, batch: Tree, cfg: ModelConfig, *,
+            dropless: bool = False, **_):
+    if not cfg.attn_layer_period:
+        return _zamba_loss(params, batch, cfg)
+    x = _pattern_embed(params, batch["tokens"], cfg)
+    x, _, aux = _pattern_stack(params, x, cfg, "train", None, None,
+                               remat=True, dropless=dropless)
+    x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
+    w = _unembed(params, cfg) / jnp.asarray(cfg.logits_scaling, x.dtype)
+    ce = L.chunked_cross_entropy(x, w, batch["labels"])
+    return ce + 0.01 * aux, {"ce": ce, "aux": aux}
+
+
+def prefill(params: Tree, batch: Tree, cfg: ModelConfig, *,
+            dropless: bool = False, **_):
+    if not cfg.attn_layer_period:
+        return _zamba_prefill(params, batch, cfg)
+    x = _pattern_embed(params, batch["tokens"], cfg)
+    x, cache, _ = _pattern_stack(params, x, cfg, "prefill", None, None,
+                                 remat=False, dropless=dropless)
+    x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
+    return _pattern_logits(params, x[:, -1], cfg), cache
+
+
+def decode_step(params: Tree, cache: Tree, batch: Tree, cfg: ModelConfig, *,
+                dropless: bool = False, **_):
+    """Returns (logits [B,V], new cache); the served MoE of a layer-pattern
+    stack (``dropless``) adds each row's assignments to held experts over
+    all layers [B]."""
+    if not cfg.attn_layer_period:
+        return _zamba_decode(params, cache, batch, cfg)
+    x = _pattern_embed(params, batch["tokens"][:, None], cfg)
+    x, ncache, aux = _pattern_stack(params, x, cfg, "decode", cache,
+                                    batch["cur_index"], remat=False,
+                                    dropless=dropless)
+    x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
+    logits = _pattern_logits(params, x[:, 0], cfg)
+    if dropless:
+        return logits, ncache, aux[:, 0]
+    return logits, ncache
+
+
 def abstract_cache(cfg: ModelConfig, batch: int, seq_len: int) -> Tree:
     d_inner, n_heads, conv_dim, _ = _dims(cfg)
-    n_p, every, tail = _periods(cfg)
-    nl = cfg.num_layers
+    if cfg.attn_layer_period:
+        n_p, period, _ = hybrid_pattern(cfg)
+        nl, n_attn = n_p * (period - 1), n_p
+    else:
+        n_p, every, tail = _periods(cfg)
+        nl, n_attn = cfg.num_layers, n_p if cfg.hybrid_attn_every else 0
     c: Tree = {
         "mamba": (
             ParamSpec((nl, batch, conv_dim, CONV_WIDTH - 1),
@@ -307,9 +542,9 @@ def abstract_cache(cfg: ModelConfig, batch: int, seq_len: int) -> Tree:
                       ("layers", "batch", "ssm_heads", None, None), "float32", "zeros"),
         )
     }
-    if cfg.hybrid_attn_every and n_p:
+    if n_attn:
         kv, hd = cfg.resolved_kv_heads, cfg.resolved_head_dim
-        shape = (n_p, batch, kv, seq_len, hd)
+        shape = (n_attn, batch, kv, seq_len, hd)
         logical = ("layers", "batch", "cache_kv_heads", "cache_seq", None)
         cd = cfg.resolved_cache_dtype
         c["attn"] = (ParamSpec(shape, logical, cd, "zeros"),

@@ -5,6 +5,7 @@
   loss_fn(params, batch, cfg)          -> (loss, metrics)       [train_4k]
   prefill(params, batch, cfg)          -> (logits, cache)       [prefill_32k]
   decode_step(params, cache, batch, cfg)-> (logits, cache)      [decode shapes]
+      (with dropless=True an MoE model adds its held-expert rows [B])
   abstract_cache(cfg, B, S)            -> ParamSpec tree
   input_specs(cfg, shape)              -> ParamSpec tree for the batch
 """
@@ -101,9 +102,9 @@ def count_active_params(cfg: ModelConfig) -> int:
     total = count(tree)
     if cfg.num_experts == 0:
         return total
-    inactive_frac = 1.0 - cfg.top_k / cfg.num_experts
+    inactive_frac = 1.0 - cfg.top_k / cfg.resolved_router_experts
     expert = 0
-    layers = tree["layers"]
+    layers = tree["moe"] if "moe" in tree else tree["layers"]
     for name in ("we_gate", "we_up", "we_down"):
         expert += int(np.prod(layers[name].shape))
     return int(total - expert * inactive_frac)

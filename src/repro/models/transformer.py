@@ -23,7 +23,8 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.models import layers as L
-from repro.models.moe import moe_ffn, moe_ffn_dense_fallback, moe_param_specs
+from repro.models.moe import (moe_ffn, moe_ffn_dense_fallback, moe_ffn_served,
+                              moe_param_specs)
 from repro.models.param import ParamSpec, constrain
 
 Tree = Dict[str, Any]
@@ -128,10 +129,15 @@ def _attention(x, lp, cfg: ModelConfig, mode, sincos, window, cache, cur_index):
     if cfg.qk_norm:
         q = L.rms_norm(q, lp["q_norm"], cfg.norm_eps)
         k = L.rms_norm(k, lp["k_norm"], cfg.norm_eps)
-    sin, cos = sincos
-    rd = cfg.resolved_head_dim // 2 if cfg.rope_2d else cfg.resolved_head_dim
-    q = L.apply_rope(q, sin, cos, rd)
-    k = L.apply_rope(k, sin, cos, rd)
+    if cfg.rope:
+        sin, cos = sincos
+        rd = cfg.resolved_head_dim // 2 if cfg.rope_2d else cfg.resolved_head_dim
+        q = L.apply_rope(q, sin, cos, rd)
+        k = L.apply_rope(k, sin, cos, rd)
+    if cfg.attention_multiplier:
+        # the kernels scale scores by 1/sqrt(head_dim); fold the rest into q
+        q = q * jnp.asarray(cfg.attention_multiplier
+                            * cfg.resolved_head_dim ** 0.5, q.dtype)
     q = constrain(q, "batch", "seq", "act_heads", None)
 
     new_cache = None
@@ -206,13 +212,24 @@ def _attention(x, lp, cfg: ModelConfig, mode, sincos, window, cache, cur_index):
     return jnp.einsum("bshk,hkd->bsd", att, wo), new_cache
 
 
-def _ffn(x, lp, cfg: ModelConfig, moe: bool, dropless: bool):
+def served_moe(mode: str, dropless: bool) -> bool:
+    """Dropless serving (prefill and decode) takes the served MoE path,
+    whose second output is each token's count of held-expert rows."""
+    return dropless and mode != "train"
+
+
+def _ffn(x, lp, cfg: ModelConfig, moe: bool, dropless: bool, mode: str,
+         layer=None):
+    """-> (delta, aux): aux is the router's balance loss, or with the
+    served path (``served_moe``) each token's held-expert rows [B,S]."""
     h = L.rms_norm(x, lp["mlp_norm" if not moe else "moe_norm"], cfg.norm_eps)
     if not moe:
         return L.swiglu(h,
                         _gathered(lp["w_gate"], cfg, None, "mlp"),
                         _gathered(lp["w_up"], cfg, None, "mlp"),
                         _gathered(lp["w_down"], cfg, "mlp", None)), 0.0
+    if served_moe(mode, dropless):
+        return moe_ffn_served(h, lp, cfg, layer)
     fn = moe_ffn_dense_fallback if dropless else moe_ffn
     return fn(h, lp, cfg)
 
@@ -220,7 +237,7 @@ def _ffn(x, lp, cfg: ModelConfig, moe: bool, dropless: bool):
 def _layer(x, lp, cfg, mode, sincos, window, cache, cur_index, moe, dropless):
     delta, new_cache = _attention(x, lp, cfg, mode, sincos, window, cache, cur_index)
     x = x + delta
-    ff, aux = _ffn(x, lp, cfg, moe, dropless)
+    ff, aux = _ffn(x, lp, cfg, moe, dropless, mode)
     x = x + ff
     x = constrain(x, "batch", "seq_res", "act_embed")
     return x, new_cache, aux
@@ -228,6 +245,8 @@ def _layer(x, lp, cfg, mode, sincos, window, cache, cur_index, moe, dropless):
 
 # ----------------------------------------------------------------- forward
 def _sincos(cfg: ModelConfig, positions: jax.Array):
+    if not cfg.rope:
+        return None
     rd = cfg.resolved_head_dim // 2 if cfg.rope_2d else cfg.resolved_head_dim
     return L.rope_freqs(positions, cfg.resolved_head_dim, cfg.rope_theta, rd)
 
@@ -256,7 +275,9 @@ def _stack_forward(
     moe = cfg.num_experts > 0
     n_periods, period, tail = layer_pattern(cfg)
 
-    aux_total = jnp.zeros((), jnp.float32)
+    # served MoE: per-token held-expert rows, summed over the layers
+    aux_total = jnp.zeros(x.shape[:2] if moe and served_moe(mode, dropless)
+                          else (), jnp.float32)
     new_cache: Dict[str, Any] = {}
 
     # ---- leading dense layers (deepseek-moe) --------------------------------
@@ -398,15 +419,18 @@ def prefill(params: Tree, batch: Tree, cfg: ModelConfig, *, dropless: bool = Fal
 def decode_step(params: Tree, cache: Tree, batch: Tree, cfg: ModelConfig, *,
                 dropless: bool = False):
     """batch: tokens [B] (new token ids), cur_index scalar int32.
-    Returns (logits [B,V], new_cache)."""
+    Returns (logits [B,V], new_cache); the served MoE (``dropless``) adds
+    each row's assignments to held experts over all layers [B]."""
     tokens = batch["tokens"][:, None]
     x = _embed(params, cfg, tokens, None)
-    x, new_cache, _ = _stack_forward(
+    x, new_cache, aux = _stack_forward(
         params, x, cfg, "decode", cache, batch["cur_index"], remat=False,
         dropless=dropless,
     )
     x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = (x[:, 0] @ _unembed_matrix(params, cfg)).astype(jnp.float32)
+    if cfg.num_experts and served_moe("decode", dropless):
+        return logits, new_cache, aux[:, 0]
     return logits, new_cache
 
 

@@ -94,7 +94,9 @@ def make_prefill_fn(engine: ServingEngine) -> Callable[[Any], Any]:
             if sp:
                 sp.set_metadata(uids=profiling.batch_uids(),
                                 bytes=sum(profiling.nbytes_arg(pages)
-                                          for pages in rows))
+                                          for pages in rows),
+                                state_bytes=sum(engine.state_nbytes(pages[1:])
+                                                for pages in rows))
         out = []
         for i, pages in enumerate(rows):
             out.append(KVPages(
@@ -149,6 +151,8 @@ class ContinuousDecoder:
         self._free: List[int] = list(range(max_slots - 1, -1, -1))
         self.stats = {"admitted": 0, "completed": 0, "segments": 0,
                       "abandoned": 0, "max_resident": 0, "max_parked": 0}
+        if engine.moe_layers:
+            self.stats.update(expert_rows=0, expert_rows_absent=0)
 
     # ------------------------------------------------------------- protocol
     def __call__(self, payload: Any, *, uid: str):
@@ -160,6 +164,16 @@ class ContinuousDecoder:
             self.stats["max_parked"] = max(self.stats["max_parked"],
                                            len(self._waiting))
         return DEFERRED
+
+    def expert_rows_per_step(self) -> float:
+        """Routed rows each held expert of a layer took per decode step,
+        on average over the segments run (0 for a model without experts)."""
+        layers = self.engine.moe_layers
+        steps = self.stats["segments"] * self.segment_len
+        if not layers or not steps:
+            return 0.0
+        return self.stats["expert_rows"] / (
+            steps * layers * self.engine.cfg.num_experts)
 
     def pending(self) -> int:
         with self._lock:
@@ -188,7 +202,9 @@ class ContinuousDecoder:
                 with span("onepiece.decode.insert", uid=uid, slot=slot,
                           start=kv.meta["start"]) as sp:
                     if sp:
-                        sp.set_metadata(bytes=profiling.nbytes_arg([kv]))
+                        sp.set_metadata(bytes=profiling.nbytes_arg([kv]),
+                                        state_bytes=self.engine.state_nbytes(
+                                            kv.pages[1:]))
                     self._state = self.engine.insert_slot(
                         self._state, slot, cache1, kv.pages[0],
                         start=kv.meta["start"], seed=kv.meta["seed"],
@@ -207,6 +223,10 @@ class ContinuousDecoder:
                 self._state, toks, adv = self.engine.decode_segment(
                     self._state, self.segment_len)
             self.stats["segments"] += 1
+            if "expert_rows" in self._state:
+                held, absent = np.asarray(self._state["expert_rows"])
+                self.stats["expert_rows"] += int(held)
+                self.stats["expert_rows_absent"] += int(absent)
             for slot, ent in list(self._slots.items()):
                 fresh = toks[adv[:, slot], slot]
                 if fresh.size:

@@ -100,6 +100,14 @@ class ServingEngine:
         cfgs = cfg
         max_len_s = max_len
 
+        # MoE models tally, per decode segment, the routed (row, expert)
+        # assignments of the rows that advanced: those landing on experts
+        # held here, and those routed to experts held elsewhere.
+        self.moe_layers = (cfg.num_layers - cfg.first_dense_layers
+                           if cfg.num_experts else 0)
+        moe = self.moe_layers > 0
+        routed_per_row = self.moe_layers * cfg.top_k
+
         # Per-leaf batch-axis map: every family's abstract_cache ParamSpec
         # names its batch dim "batch", but at a different position per leaf
         # (stacked-layer leading dims, gemma3 period dims) — this tree is
@@ -107,6 +115,11 @@ class ServingEngine:
         spec1 = registry.abstract_cache(cfg, 1, max_len)
         self._batch_axes = jax.tree.map(
             lambda s: s.logical.index("batch"), spec1, is_leaf=is_spec)
+        # which cache leaves (flatten order) are recurrent state, fixed in
+        # size, and which a KV cache that grows along "cache_seq"
+        self.state_leaves = [
+            "cache_seq" not in s.logical
+            for s in jax.tree.leaves(spec1, is_leaf=is_spec)]
 
         @jax.jit
         def prefill_fn(params, batch):
@@ -132,7 +145,7 @@ class ServingEngine:
         def decode_fn(params, cache, tokens, cur_index):
             return registry.decode_step(
                 params, cache, {"tokens": tokens, "cur_index": cur_index},
-                cfgs, dropless=True)
+                cfgs, dropless=True)[:2]
 
         @functools.partial(jax.jit, static_argnames=("steps", "temperature"))
         def decode_loop_fn(params, cache, logits, start, seed, *, steps,
@@ -152,7 +165,7 @@ class ServingEngine:
                 tok = jnp.minimum(tok, cfgs.vocab_size - 1).astype(jnp.int32)
                 logits, cache = registry.decode_step(
                     params, cache, {"tokens": tok, "cur_index": idx},
-                    cfgs, dropless=True)
+                    cfgs, dropless=True)[:2]
                 return (logits, cache, idx + 1), tok
 
             (logits, cache, _), toks = jax.lax.scan(
@@ -169,23 +182,24 @@ class ServingEngine:
                     big, jnp.asarray(small, big.dtype), slot, axis=ax),
                 state["cache"], cache1, self._batch_axes)
             key = jax.random.fold_in(jax.random.PRNGKey(seed), 0)
-            return {
-                "cache": cache,
-                "logits": state["logits"].at[slot].set(logits1),
-                "cur_index": state["cur_index"].at[slot].set(start),
-                "step": state["step"].at[slot].set(0),
-                "remaining": state["remaining"].at[slot].set(rem),
-                "keys": state["keys"].at[slot].set(key),
-                "temp": state["temp"].at[slot].set(temp),
-                "active": state["active"].at[slot].set(True),
-            }
+            return dict(
+                state, cache=cache,
+                logits=state["logits"].at[slot].set(logits1),
+                cur_index=state["cur_index"].at[slot].set(start),
+                step=state["step"].at[slot].set(0),
+                remaining=state["remaining"].at[slot].set(rem),
+                keys=state["keys"].at[slot].set(key),
+                temp=state["temp"].at[slot].set(temp),
+                active=state["active"].at[slot].set(True))
 
         @functools.partial(jax.jit, static_argnames=("k",))
         def segment_fn(params, state, *, k):
             """k decode steps over the whole slot batch.  Slots advance only
             while active with budget remaining; the rest decode masked-out
             garbage (row-independent math, overwritten at next insert).
-            Returns (state', toks [k, N], advanced-mask [k, N])."""
+            Returns (state', toks [k, N], advanced-mask [k, N]).  An MoE
+            model's state carries beside them ``expert_rows``: [held,
+            absent] assignments of the advanced rows in this segment."""
 
             def body(carry, _):
                 logits, cache, cur, step, rem, keys, temp, active = carry
@@ -193,22 +207,30 @@ class ServingEngine:
                 tok = _sample_rows(logits, step_keys, temp)
                 tok = jnp.minimum(tok, cfgs.vocab_size - 1).astype(jnp.int32)
                 adv = active & (rem > 0)
-                new_logits, cache = registry.decode_step(
+                out = registry.decode_step(
                     params, cache, {"tokens": tok, "cur_index": cur},
                     cfgs, dropless=True)
+                new_logits, cache = out[:2]
+                held = jnp.where(adv, out[2], 0.0) if moe else None
                 logits = jnp.where(adv[:, None], new_logits, logits)
                 ai = adv.astype(jnp.int32)
                 carry = (logits, cache, cur + ai, step + ai, rem - ai,
                          keys, temp, active)
-                return carry, (tok, adv)
+                return carry, (tok, adv, held)
 
             carry = (state["logits"], state["cache"], state["cur_index"],
                      state["step"], state["remaining"], state["keys"],
                      state["temp"], state["active"])
-            carry, (toks, adv) = jax.lax.scan(body, carry, None, length=k)
+            carry, (toks, adv, held) = jax.lax.scan(body, carry, None,
+                                                    length=k)
             logits, cache, cur, step, rem, keys, temp, active = carry
             state = dict(state, logits=logits, cache=cache, cur_index=cur,
                          step=step, remaining=rem)
+            if moe:
+                n_held = held.sum().astype(jnp.int32)
+                n_adv = adv.sum().astype(jnp.int32)
+                state["expert_rows"] = jnp.stack(
+                    [n_held, n_adv * routed_per_row - n_held])
             return state, toks, adv
 
         @jax.jit
@@ -265,6 +287,24 @@ class ServingEngine:
                     for s in jax.tree.leaves(spec1, is_leaf=is_spec))
         return cache + 4 * self.cfg.vocab_padded
 
+    def state_nbytes(self, leaves) -> int:
+        """Bytes of the recurrent-state leaves among one request's cache
+        ``leaves`` (flatten order); the rest of its bytes are KV."""
+        return sum(int(leaf.nbytes)
+                   for leaf, st in zip(leaves, self.state_leaves) if st)
+
+    @property
+    def handoff_split(self) -> Dict[str, int]:
+        """One request's cache at ``max_len``: bytes of recurrent state and
+        of KV."""
+        spec1 = registry.abstract_cache(self.cfg, 1, self.max_len)
+        out = {"state_bytes": 0, "kv_bytes": 0}
+        for s, st in zip(jax.tree.leaves(spec1, is_leaf=is_spec),
+                         self.state_leaves):
+            n = int(np.prod(s.shape)) * jnp.dtype(s.dtype).itemsize
+            out["state_bytes" if st else "kv_bytes"] += n
+        return out
+
     def init_slots(self, max_slots: int) -> Dict[str, Any]:
         """Fresh continuous-batching decode state: a ``max_slots``-wide slot
         cache plus per-slot sampling/progress vectors, all inactive."""
@@ -276,7 +316,10 @@ class ServingEngine:
         cache = jax.tree.map(lambda s: jnp.zeros(s.shape, jnp.dtype(s.dtype)),
                              spec, is_leaf=is_spec)
         n, v = max_slots, self.cfg.vocab_padded
+        tally = {"expert_rows": jnp.zeros((2,), jnp.int32)} \
+            if self.moe_layers else {}
         return {
+            **tally,
             "cache": cache,
             "logits": jnp.zeros((n, v), jnp.float32),
             "cur_index": jnp.zeros((n,), jnp.int32),

@@ -108,7 +108,7 @@ def test_prefill_and_decode_shapes(arch):
             params, c,
             {"tokens": jnp.full((b,), 3, jnp.int32), "cur_index": jnp.int32(t)},
             cfg, dropless=True,
-        )
+        )[:2]
         assert logits.shape == (b, cfg.vocab_padded)
         assert np.isfinite(np.asarray(logits)).all()
 
@@ -132,7 +132,7 @@ def test_decode_matches_prefill(arch):
     got = []
     for t in range(s):
         step_batch = {"tokens": tokens[:, t], "cur_index": jnp.int32(t)}
-        logits, c = R.decode_step(params, c, step_batch, cfg, dropless=True)
+        logits, c = R.decode_step(params, c, step_batch, cfg, dropless=True)[:2]
         got.append(np.asarray(logits))
 
     for t in (0, s // 2, s - 1):

@@ -64,7 +64,8 @@ def _quiesce(ws, proxy, uids, timeout_s: float = 30.0):
     return results
 
 
-FAMILIES = {"qwen3": "qwen3-1.7b", "rwkv6": "rwkv6-7b"}
+FAMILIES = {"qwen3": "qwen3-1.7b", "rwkv6": "rwkv6-7b",
+            "granite": "granite-4.0-h-small"}
 
 
 def _engine(arch):
@@ -79,8 +80,8 @@ def engine():
 
 @pytest.fixture(scope="module")
 def engines(engine):
-    """``family -> engine``: a KV family (qwen3) and a recurrent-state
-    family (rwkv6)."""
+    """``family -> engine``: a KV family (qwen3), a recurrent-state
+    family (rwkv6), and a hybrid of both with sparse experts (granite)."""
     made = {"qwen3": engine}
 
     def get(family):
@@ -112,7 +113,7 @@ def _solo(engine, payload):
 
 
 # ============================================================ happy path
-@pytest.mark.parametrize("family", ["qwen3", "rwkv6"])
+@pytest.mark.parametrize("family", ["qwen3", "rwkv6", "granite"])
 def test_disagg_end_to_end_matches_solo_generate(engines, family):
     """Two-stage prefill→decode over the fabric, three requests sharing
     the slot batch: every result is bit-identical to a solo generate, the
@@ -572,3 +573,39 @@ def test_continuous_batching_random_join_leave_property(engines, family):
     for uid, toks in got.items():
         np.testing.assert_array_equal(toks, expected[uid])
     assert dec.stats["max_resident"] <= 3
+
+
+def test_decoder_tallies_the_rows_of_held_and_absent_experts():
+    """A hybrid MoE model holding 2 of the 4 experts its router spans:
+    per segment the decoder counts each advanced row's routed assignments,
+    those on experts held here and those on experts held elsewhere; the
+    two add up to the tokens decoded x MoE layers x experts per token.  A
+    handoff carries recurrent state (the Mamba2 states) and KV (the
+    attention layers'), told apart by the engine."""
+    cfg = dataclasses.replace(get_config(FAMILIES["granite"]).reduced(),
+                              dtype="float32")
+    cfg = dataclasses.replace(cfg, num_experts=2, router_experts=4,
+                              expert_offset=2)
+    engine = ServingEngine(cfg, max_len=64)
+    dec = ContinuousDecoder(engine, max_slots=2, segment_len=3)
+    prefill = make_prefill_fn(engine)
+    steps = [5, 7, 4]
+    for i, n in enumerate(steps):
+        dec(prefill(_payload(engine, i, steps=n, temperature=0.0)),
+            uid=f"u{i}")
+    done = {}
+    while len(done) < len(steps):
+        done.update(dec.tick())
+    st = dec.stats
+    routed = sum(steps) * cfg.num_layers * cfg.top_k
+    assert st["expert_rows"] + st["expert_rows_absent"] == routed
+    assert 0 < st["expert_rows"] < routed
+    assert dec.expert_rows_per_step() == pytest.approx(
+        st["expert_rows"] / (st["segments"] * 3 * cfg.num_layers * 2))
+    split = engine.handoff_split
+    assert split["state_bytes"] > 0 and split["kv_bytes"] > 0
+    # leaves (flatten order): the attention layers' (k, v), the Mamba2
+    # layers' (conv, ssd)
+    assert engine.state_leaves == [False, False, True, True]
+    assert "expert_rows" not in ContinuousDecoder(
+        _engine(FAMILIES["qwen3"]), max_slots=1).stats

@@ -19,6 +19,8 @@ from repro.kernels.flash_attention import flash_attention
 from repro.kernels.flash_attention.ref import attention_ref
 from repro.kernels.rwkv6_wkv import wkv6
 from repro.kernels.rwkv6_wkv.ref import wkv6_ref
+from repro.kernels.ssd_scan import ssd_scan
+from repro.kernels.ssd_scan.ref import ssd_ref
 
 try:
     from hypothesis import given, settings, strategies as st
@@ -78,6 +80,47 @@ def test_flash_attention_matches_model_layer():
     ker = flash_attention(q, k, v, causal=True)
     mod = attention_blockwise(q, k, v, causal=True, q_block=64, kv_block=64)
     np.testing.assert_allclose(np.asarray(ker), np.asarray(mod), atol=3e-5, rtol=3e-5)
+
+
+# -------------------------------------------------------------------- ssd
+def _ssd_inputs(b, t, h, p, n, key=0):
+    ks = jax.random.split(jax.random.PRNGKey(key), 6)
+    x = jax.random.normal(ks[0], (b, t, h, p))
+    dt = jax.nn.softplus(jax.random.normal(ks[1], (b, t, h)) - 1.0)
+    la = -jnp.exp(jax.random.normal(ks[2], (h,)))[None, None] * dt
+    B = jax.random.normal(ks[3], (b, t, n))
+    C = jax.random.normal(ks[4], (b, t, n))
+    s0 = jax.random.normal(ks[5], (b, h, p, n))       # a carried state
+    return x, dt, la, B, C, s0
+
+
+@pytest.mark.parametrize("t,chunk", [(200, 64), (37, 128), (128, 128)])
+def test_ssd_kernel_matches_the_scan(t, chunk):
+    """The chunked SSD kernel (interpret mode) against the sequential scan,
+    from a non-zero initial state, at lengths that are not a multiple of
+    the chunk (padded steps must leave the state alone)."""
+    args = _ssd_inputs(2, t, 3, 16, 32)
+    with jax.default_matmul_precision("highest"):
+        yr, sr = ssd_ref(*args)
+    y, s = ssd_scan(*args, chunk=chunk, interpret=True)
+    scale = float(jnp.abs(yr).max())
+    np.testing.assert_allclose(np.asarray(y), np.asarray(yr),
+                               atol=2e-5 * scale, rtol=0)
+    np.testing.assert_allclose(np.asarray(s), np.asarray(sr),
+                               atol=2e-5 * float(jnp.abs(sr).max()), rtol=0)
+
+
+def test_ssd_kernel_matches_the_model_scan():
+    from repro.models.mamba2 import ssd_scan as model_scan
+
+    args = _ssd_inputs(1, 96, 2, 8, 16, key=3)
+    with jax.default_matmul_precision("highest"):
+        y_m, s_m = model_scan(*args, use_pallas=False)
+    y_k, s_k = ssd_scan(*args, chunk=32, interpret=True)
+    np.testing.assert_allclose(np.asarray(y_k), np.asarray(y_m),
+                               atol=1e-4, rtol=1e-4)
+    np.testing.assert_allclose(np.asarray(s_k), np.asarray(s_m),
+                               atol=1e-4, rtol=1e-4)
 
 
 # ------------------------------------------------------------------- wkv6

@@ -82,7 +82,7 @@ def test_int8_cache_decode_close_to_f32(arch):
         for t in range(8):
             logits, c = R.decode_step(
                 params, c, {"tokens": jnp.asarray(toks[:, t]),
-                            "cur_index": jnp.int32(t)}, cfg, dropless=True)
+                            "cur_index": jnp.int32(t)}, cfg, dropless=True)[:2]
         return np.asarray(logits)
 
     l8, lf = run(cfg8), run(cfgf)

@@ -22,10 +22,11 @@ SPANS = {
     "onepiece.sched.wait": {"instance"},
     "onepiece.recv": {"instance", "uid", "bytes"},
     "onepiece.stage": {"instance", "stage", "uids"},
-    "onepiece.handoff.pull": {"uids", "bytes", "placement"},
+    "onepiece.handoff.pull": {"uids", "bytes", "state_bytes", "placement"},
     "onepiece.deliver": {"instance", "stage", "uids", "bytes"},
     "onepiece.decode.tick": {"seq"},
-    "onepiece.decode.insert": {"uid", "slot", "start", "bytes"},
+    "onepiece.decode.insert": {"uid", "slot", "start", "bytes",
+                               "state_bytes"},
     "onepiece.decode.segment": {"seq", "k"},
     "onepiece.decode.first_token": {"uid"},
 }

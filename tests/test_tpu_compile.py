@@ -27,11 +27,13 @@ from repro.kernels import (
     decode_attention_cache,
     decode_attention_int8_cache,
     flash_attention,
+    ssd_scan,
     wkv6,
 )
 
 QWEN3 = get_config("qwen3-1.7b")
 RWKV6 = get_config("rwkv6-7b")
+GRANITE = get_config("granite-4.0-h-small")
 
 
 @pytest.fixture(scope="module")
@@ -65,7 +67,7 @@ def _compile(fn, one_chip, *shapes):
 def _op_names(compiled):
     """The ops of the compiled program by name, as the device trace names
     them (``%<name>.<n> = ...``): the benchmark's kernel reducers match the
-    prefixes ``flash_attention`` and ``wkv6``."""
+    prefixes ``flash_attention``, ``wkv6`` and ``ssd_scan``."""
     return set(re.findall(r"^\s*%([A-Za-z_][\w\-]*?)(?:\.\d+)? = ",
                           compiled.as_text(), re.M))
 
@@ -116,3 +118,17 @@ def test_wkv6_compiles_at_rwkv6_7b_widths(one_chip):
         one_chip, seq, seq, seq, seq, ((h, kk), jnp.bfloat16),
         ((1, h, kk, kk), jnp.float32))
     assert any(n.startswith("wkv6") for n in _op_names(compiled))
+
+
+@pytest.mark.parametrize("seq", [256, 1536])
+def test_ssd_scan_compiles_at_granite_widths(one_chip, seq):
+    p, n = GRANITE.ssm_head_dim, GRANITE.ssm_state
+    h = GRANITE.d_inner // p
+    f32 = jnp.float32
+    compiled = _compile(
+        lambda x, dt, la, b, c, s: ssd_scan(x, dt, la, b, c, s,
+                                            interpret=False),
+        one_chip, ((1, seq, h, p), f32), ((1, seq, h), f32),
+        ((1, seq, h), f32), ((1, seq, n), f32), ((1, seq, n), f32),
+        ((1, h, p, n), f32))
+    assert any(n.startswith("ssd_scan") for n in _op_names(compiled))
